@@ -34,9 +34,9 @@ def run():
     c_s = jax.jit(f_scan).lower(x, ws).compile()
     c_u = jax.jit(f_unroll).lower(x, ws).compile()
     parsed = RL.parse_hlo(c_s.as_text()).dot_flops
-    ref_flops = RL.xla_cost(c_u)["flops"]
+    ref_flops = c_u.cost_analysis()["flops"]
     rows.append({"case": "scan8-matmul",
-                 "xla_cost_analysis_flops": RL.xla_cost(c_s)["flops"],
+                 "xla_cost_analysis_flops": c_s.cost_analysis()["flops"],
                  "unrolled_reference_flops": ref_flops,
                  "loop_aware_parser_flops": parsed,
                  "parser_vs_ref": round(parsed / ref_flops, 4)})
@@ -55,7 +55,7 @@ def run():
 
         comp = jax.jit(fwd).lower(params, tokens).compile()
         parsed = RL.parse_hlo(comp.as_text())
-        xla = RL.xla_cost(comp)["flops"]
+        xla = comp.cost_analysis()["flops"]
         rows.append({"case": f"{arch}-fwd-loss",
                      "xla_cost_analysis_flops": xla,
                      "unrolled_reference_flops": "",

@@ -97,13 +97,12 @@ def _one(m: int, k: int, n: int, bits: int) -> dict:
     wp = packing.pack(w_idx, bits)
     planes = packing.pack_bitplanes_signed(w_idx, bits)
     a_idx = jnp.asarray(rng.integers(0, 2 ** bits, (m, k)), jnp.uint8)
-    ap = packing.pack(a_idx, bits)
     plut = lut.product_lut(cb, cb)
 
     bf = _aot(lambda a, w: a @ w, a_bf, w_bf)
     dq = _aot(lambda a, w: ref.ref_dequant_matmul(
         a, w, cb.levels, scales, bits), a_f32, wp)
-    lg = _aot(lambda a, w: ref.ref_lut_gemm(a, w, plut), ap, wp)
+    lg = _aot(lambda a, w: ref.ref_lut_gemm(a, w, plut), a_idx, wp)
     bs = _aot(lambda a, w: ref.ref_lut_gemm_bitsliced(a, w, bits=bits),
               a_i8, planes)
     fu = _aot(lambda a, w, sc: ref.ref_lut_gemm_bs_fused(
@@ -112,7 +111,7 @@ def _one(m: int, k: int, n: int, bits: int) -> dict:
     t_bf, t_dq, t_lg, t_bs, t_fu = _time_routes([
         (bf, (a_bf, w_bf)),
         (dq, (a_f32, wp)),
-        (lg, (ap, wp)),
+        (lg, (a_idx, wp)),
         (bs, (a_i8, planes)),
         (fu, (a_bf, planes, scales)),
     ])

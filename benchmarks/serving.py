@@ -34,8 +34,10 @@ Workloads:
    per-channel MSE.
 
 5. Tensor-parallel serving (subprocess, 8 fake CPU devices): the engine on
-   a --tp 8 "model" mesh vs the single-device engine. CI gates: bf16 greedy
-   output token-identical, planned w2a2 run-to-run deterministic with a
+   a --tp 8 "model" mesh vs the single-device engine. This phase is a CPU
+   emulation that never touches the chip (chip_smoke.py --chips 4 runs the
+   tensor-parallel path there); a failed child fails the run. CI gates:
+   bf16 greedy output token-identical, planned w2a2 run-to-run deterministic with a
    nonzero lut_gemm dispatch count, zero steady-state recompiles, and
    per-device weight bytes < 25% of the replicated footprint.
 
@@ -685,7 +687,9 @@ print("TPJSON:" + json.dumps({
 
 def _tp_serving() -> dict:
     """Run the tensor-parallel comparison in a subprocess with 8 fake CPU
-    devices (the fake-device flag must not leak into this process's jax)."""
+    devices (the fake-device flag must not leak into this process's jax).
+    A CPU emulation only: it never touches the chip. Raises when the child
+    fails."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["JAX_PLATFORMS"] = "cpu"
@@ -693,7 +697,8 @@ def _tp_serving() -> dict:
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(_TP_SCRIPT)],
                        capture_output=True, text=True, env=env, timeout=1200)
     if r.returncode != 0:
-        return {"error": r.stderr[-2000:]}
+        raise RuntimeError(f"tensor-parallel phase failed (exit "
+                           f"{r.returncode}):\n{r.stderr[-2000:]}")
     line = next(ln for ln in r.stdout.splitlines() if ln.startswith("TPJSON:"))
     return json.loads(line[len("TPJSON:"):])
 
@@ -810,14 +815,11 @@ def run(json_out: str = "BENCH_serving.json") -> dict:
     print("[serving] tensor-parallel engine: tp=8 on fake CPU devices "
           "(subprocess)", flush=True)
     tp = _tp_serving()
-    if "error" in tp:
-        print(f"[serving]   TP run FAILED: {tp['error'][:400]}", flush=True)
-    else:
-        print(f"[serving]   token-identical {tp['token_identical']}, w2a2 "
-              f"deterministic {tp['deterministic_w2a2']}, per-device weights "
-              f"{tp['per_device_weight_fraction']}x replicated, lut_gemm "
-              f"dispatches {tp['kernel_dispatches'].get('lut_gemm', 0)}",
-              flush=True)
+    print(f"[serving]   token-identical {tp['token_identical']}, w2a2 "
+          f"deterministic {tp['deterministic_w2a2']}, per-device weights "
+          f"{tp['per_device_weight_fraction']}x replicated, lut_gemm "
+          f"dispatches {tp['kernel_dispatches'].get('lut_gemm', 0)}",
+          flush=True)
 
     same_tokens = paged["outputs"] == dense["outputs"]
     result = {

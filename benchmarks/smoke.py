@@ -43,18 +43,18 @@ def _one_shape(m: int, k: int, n: int, bits: int) -> dict:
     wpack = jax.jit(lambda x: packing.pack(x, bits)).lower(w_idx).compile()
     ap, wp = pack(a_idx), wpack(w_idx)
     gemm = jax.jit(lambda a, w: ref.ref_lut_gemm(a, w, plut)) \
-        .lower(ap, wp).compile()
+        .lower(a_idx, wp).compile()
     dq = jax.jit(lambda a, w: ref.ref_dequant_gemm(
-        a, w, cb.levels, cb.levels, bits, bits)).lower(ap, wp).compile()
+        a, w, cb.levels, cb.levels, bits)).lower(a_idx, wp).compile()
     t_compile = time.perf_counter() - t0
 
-    got = gemm(ap, wp)
-    want = dq(ap, wp)
+    got = gemm(a_idx, wp)
+    want = dq(a_idx, wp)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     t_pack = timeit(pack, a_idx)
-    t_lut = timeit(gemm, ap, wp)
-    t_dq = timeit(dq, ap, wp)
+    t_lut = timeit(gemm, a_idx, wp)
+    t_dq = timeit(dq, a_idx, wp)
     return {
         "m": m, "k": k, "n": n, "bits": bits, "pack_factor": f,
         "lut_gemm_exact": True,

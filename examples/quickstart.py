@@ -26,11 +26,11 @@ w_scale, _ = quant.compute_scale_zero_point(w, BITS, signed=True)
 a_idx = quant.to_index(quant.quantize(a, a_scale, bits=BITS), BITS)
 w_idx = quant.to_index(quant.quantize(w, w_scale, bits=BITS), BITS)
 
-# 2. pack 4 codes per byte (16x smaller than f32, 4x smaller than int8)
-a_packed = packing.pack(a_idx, BITS)
+# 2. pack the weights 4 codes per byte (16x smaller than f32); the
+#    activation codes stay one per byte, as they are made per call
 w_packed = packing.pack(w_idx, BITS)
-print(f"A: {a.nbytes} B f32  ->  {a_packed.nbytes} B packed "
-      f"({a.nbytes // a_packed.nbytes}x)")
+print(f"W: {w.nbytes} B f32  ->  {w_packed.nbytes} B packed "
+      f"({w.nbytes // w_packed.nbytes}x)")
 
 # 3. precompute ALL 16 possible products, fused with the dequant scales
 #    (paper §5.3: quant->GEMM->dequant collapses into the table)
@@ -40,7 +40,7 @@ print(f"LUT: {table.n_entries} entries, {table.nbytes} bytes")
 
 # 4. GEMM by table lookup (Pallas kernel, interpret mode on CPU), through
 #    the KernelOp registry — the one dispatch surface every caller uses
-out = registry.dispatch("lut_gemm", a_packed, w_packed, table.table, None,
+out = registry.dispatch("lut_gemm", a_idx, w_packed, table.table, None,
                         w_bits=table.w_bits, a_bits=table.a_bits,
                         backend="pallas_interpret", block=(64, 128, 256))
 

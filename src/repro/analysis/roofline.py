@@ -47,19 +47,6 @@ _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                 "collective-permute")
 
 
-def xla_cost(compiled) -> dict:
-    """compiled.cost_analysis() as a flat dict across jax versions: jax 0.4.x
-    returns a one-entry list of per-program dicts, jax >= 0.5 the dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        merged: dict = {}
-        for c in cost:
-            for k, v in c.items():
-                merged[k] = merged.get(k, 0.0) + v
-        return merged
-    return cost
-
-
 def shape_bytes(shape_str: str) -> int:
     """Total bytes of all array shapes inside a (possibly tuple) shape str."""
     total = 0

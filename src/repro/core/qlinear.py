@@ -106,7 +106,6 @@ class QuantPolicy:
     w_bits: Optional[int] = 2          # None => bf16 layer
     a_bits: Optional[int] = None       # None => weight-only (w2a16)
     signed: bool = True
-    scheme: str = "d"                  # packing scheme for serving
     nonuniform: bool = False           # k-means codebook instead of uniform
     # layer classes to keep full precision (matched against tag components)
     skip: tuple = ("router", "embed", "norm")
@@ -145,12 +144,10 @@ W4A8 = QuantPolicy(w_bits=4, a_bits=8)
 class QuantizedWeight:
     """Serving-time packed weight for one dense layer.
 
-    packed   : (out, in/f) uint8 — packed codes along K (scheme in ``scheme``;
-               schemes 'c'/'d' are byte-identical to 'a' — the index-ready
-               trick lives in the unpack masks, see core/packing.py). The
-               bit-sliced route stores (bits, out, in/g) two's-complement
-               plane patterns instead (scheme 'bs', packing.pack_bitplanes_
-               signed)
+    packed   : (out, in/f) uint8 — packed codes along K in the natural slot
+               layout (``scheme`` 'a', packing.pack). The bit-sliced route
+               stores (bits, out, in/g) two's-complement plane patterns
+               instead (scheme 'bs', packing.pack_bitplanes_signed)
     codebook : (2^bits,) f32 — *unscaled* levels (uniform ints or k-means)
     scales   : (out,) f32 per-output-channel, or (out, K/G) group-wise when
                ``group_size`` is set (K the padded contraction axis)
@@ -233,16 +230,14 @@ jax.tree_util.register_pytree_with_keys(
 
 def _k_multiple(policy: QuantPolicy, tp_shards: int = 1) -> int:
     """Contraction-axis padding unit: the pack factor (or the scale-group
-    size, itself a pack-factor multiple), lcm'd with the ACTIVATION pack
-    factor for w{b}a{b} LUT plans, times the TP shard count for row-parallel
-    layers — so every shard holds whole packed bytes on both operands and
-    whole scale groups (a group boundary never straddles a shard split)."""
+    size, itself a pack-factor multiple), times the TP shard count for
+    row-parallel layers — so every shard holds whole packed weight bytes
+    and whole scale groups (a group boundary never straddles a shard
+    split). Activation codes are never packed, so they add nothing."""
     import math
     m = policy.group_size if policy.group_size is not None \
         else packing.PACK_FACTOR[policy.w_bits]
     kern = policy.resolved_kernel()
-    if policy.a_bits is not None and kern == "lut_gemm":
-        m = math.lcm(m, packing.PACK_FACTOR[policy.a_bits])
     if kern == "lut_gemm_bitsliced":
         # plane patterns group BITPLANE_GROUP codes per byte; activations
         # stay unpacked int8 codes, so that is the only extra constraint
@@ -259,17 +254,6 @@ def _pad_k(wt: jax.Array, multiple: int) -> jax.Array:
         cfgpad = [(0, 0)] * (wt.ndim - 1) + [(0, pad)]
         wt = jnp.pad(wt, cfgpad)
     return wt
-
-
-def _pack_for_scheme(idx: jax.Array, bits: int, scheme: str) -> jax.Array:
-    """Explicit scheme dispatch (reconciles quantize_weight with lut_gemm's
-    scheme: what is packed is what the kernel unpacks). Schemes 'c'/'d'
-    share 'a''s byte layout by construction — pack_indexready IS pack; the
-    index-ready saving is in the unpack masks — so dequant_weight's natural
-    unpack stays valid for every scheme (property-tested)."""
-    if scheme in ("c", "d"):
-        return packing.pack_indexready(idx, bits)
-    return packing.pack(idx, bits)
 
 
 def _calibrate(wt: jax.Array, bits: int, signed: bool,
@@ -345,7 +329,7 @@ def quantize_weight(w: jax.Array, policy: QuantPolicy, *,
             "bit-sliced route needs signed uniform w{b}a{b} quantization"
         packed, scheme = packing.pack_bitplanes_signed(idx, bits), "bs"
     else:
-        packed, scheme = _pack_for_scheme(idx, bits, policy.scheme), policy.scheme
+        packed, scheme = packing.pack(idx, bits), "a"
     return QuantizedWeight(
         packed=packed, codebook=levels,
         scales=scales, bits=bits,
@@ -375,11 +359,10 @@ def quantize_expert_weight(w: jax.Array, policy: QuantPolicy, *,
     kern = policy.resolved_kernel() if policy.kernel else None
     a_levels, plut = _act_tables(policy, levels)
     return QuantizedWeight(
-        packed=_pack_for_scheme(idx, bits, policy.scheme), codebook=levels,
+        packed=packing.pack(idx, bits), codebook=levels,
         scales=scales, bits=bits, in_features=w.shape[1],
         out_features=w.shape[2], group_size=G,
-        a_bits=policy.a_bits if kern == "lut_gemm" else None,
-        scheme=policy.scheme, kernel=kern,
+        a_bits=policy.a_bits if kern == "lut_gemm" else None, kernel=kern,
         a_levels=a_levels, plut=plut, tp=tp_role)
 
 
@@ -387,9 +370,8 @@ def dequant_weight(qw: QuantizedWeight) -> jax.Array:
     """Full dequantization (codebook gather + per-channel or group scale),
     returned in (in, out) / (E, in, out) orientation for einsum use. This is
     the GSPMD-shardable formulation the dry-run lowers; the Pallas kernels
-    fuse the same steps tile-wise in VMEM. (Valid for every packing scheme:
-    'c'/'d' store the same bytes as 'a'; 'bs' reassembles codes from the
-    two's-complement bit planes.)"""
+    fuse the same steps tile-wise in VMEM. (Scheme 'bs' reassembles codes
+    from the two's-complement bit planes.)"""
     idx = qw.unpacked_idx().astype(jnp.int32)                    # (..., out, in_pad)
     w = jnp.take(qw.codebook, idx)
     if qw.group_size is not None:
@@ -566,16 +548,15 @@ def dense_serve(
             y = y * a_scale if G is not None \
                 else y * qw.scales[None, :] * a_scale
         else:
-            ap = packing.pack(a_idx, a_bits)
             if qw.plut is not None and a_bits == qw.a_bits:
                 table = qw.plut
             else:
                 table = product_lut(qw.codebook, a_levels).table
             y = kreg.dispatch(
-                "lut_gemm", ap, qw.packed, table,
+                "lut_gemm", a_idx, qw.packed, table,
                 qw.scales if G is not None else None,
-                w_bits=qw.bits, a_bits=a_bits, scheme=qw.scheme,
-                group_size=G, backend=backend, block=block, tp=qw.tp)
+                w_bits=qw.bits, a_bits=a_bits, group_size=G,
+                backend=backend, block=block, tp=qw.tp)
             y = y * a_scale if G is not None \
                 else y * qw.scales[None, :] * a_scale
     y = y[:n_rows]

@@ -93,7 +93,6 @@ def make_plan(
     group_size: Optional[int] = None,
     *,
     backend: str = "auto",
-    scheme: str = "d",
     nonuniform: bool = False,
     signed: bool = True,
     a_scale: str = "dynamic",
@@ -110,7 +109,7 @@ def make_plan(
     ``tune`` lists M buckets to autotune tiles for (see QuantPlan)."""
     default = QuantPolicy(
         w_bits=w_bits, a_bits=a_bits, group_size=group_size, signed=signed,
-        scheme=scheme, nonuniform=nonuniform, kernel=kernel, a_scale=a_scale)
+        nonuniform=nonuniform, kernel=kernel, a_scale=a_scale)
     keep_rules = tuple((pattern, None) for pattern in keep)
     return QuantPlan(rules=keep_rules + tuple(rules) + (("*", default),),
                      backend=backend, tune=tuple(tune))

@@ -1,21 +1,9 @@
 """repro.dist — sharding rules, compressed collectives, pipeline parallelism
-and fault tolerance for the serving/training stack.
+and fault tolerance for the serving/training stack."""
 
-Importing this package also installs a small forward-compat shim: jax < 0.5
-exposes shard_map only under jax.experimental, while callers here use the
-stable ``jax.shard_map`` spelling.
-"""
-
-import jax
-
-if not hasattr(jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    jax.shard_map = _shard_map
-
-from . import collectives, fault, pipeline, sharding  # noqa: E402,F401
-from .fault import FaultConfig, run_resilient  # noqa: E402,F401
-from .sharding import (  # noqa: E402,F401
+from . import collectives, fault, pipeline, sharding  # noqa: F401
+from .fault import FaultConfig, run_resilient  # noqa: F401
+from .sharding import (  # noqa: F401
     PRESETS,
     TP_ROLES,
     active_tp,

@@ -57,12 +57,11 @@ def _synth_args(op_name: str, m: int, k: int, n: int, *, bits: int,
         return (a, wp, cb, scales), dict(bits=bits, group_size=group_size)
     ab = a_bits or 8
     if op_name == "lut_gemm":
-        ap = jnp.asarray(rng.integers(0, 256, (m, packing.packed_len(k, ab))),
-                         jnp.uint8)
+        a_idx = jnp.asarray(rng.integers(0, 2 ** ab, (m, k)), jnp.uint8)
         wp = jnp.asarray(rng.integers(0, 256, (n, packing.packed_len(k, bits))),
                          jnp.uint8)
         table = jnp.asarray(rng.standard_normal(2 ** (bits + ab)), jnp.float32)
-        return (ap, wp, table, scales if group_size else None), \
+        return (a_idx, wp, table, scales if group_size else None), \
             dict(w_bits=bits, a_bits=ab, group_size=group_size)
     if op_name == "lut_gemm_bitsliced":
         g = packing.BITPLANE_GROUP

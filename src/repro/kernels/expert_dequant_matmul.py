@@ -9,12 +9,13 @@ batched/grouped form of `lut_dequant_matmul`: for every expert e,
 with x[e] the (capacity-padded) tokens dispatched to e. The kernel walks a
 (E, M-tiles, N-tiles, K-tiles) grid; each step unpacks one expert's packed
 sub-byte tile in VMEM, codebook-dequantizes (uniform or k-means table — the
-paper's flexibility), and contracts on the MXU.
+paper's flexibility), and contracts on the MXU, with the dense kernel's
+slot-major tile body (lut_dequant_matmul.dequant_dot).
 
 Memory layout per grid step (be=1, bm=128, bn=128, bk=512, bits=2):
-  x tile     (bm, bk) f32/bf16      256 KiB  HBM->VMEM
+  x tile     (f, bm, bk/f) bf16     128 KiB  HBM->VMEM
   w tile     (bn, bk/4) uint8        16 KiB  HBM->VMEM  (the 8x win)
-  w dequant  (bn, bk) f32           256 KiB  VMEM only
+  w dequant  (bn, bk/4) f32          64 KiB  per slot, VMEM only
   acc        (bm, bn) f32            64 KiB  VMEM
 """
 
@@ -25,48 +26,37 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import packing
-from .lut_gemm import _expand_scales_tile, _fit, _lut_products, _unpack_natural
+from .lut_dequant_matmul import dequant_dot
+from .lut_gemm import (LANE, group_scale_tile, lut_dot, matmul_blocks,
+                       scaled_sum, slot_major)
 
 
-def _expert_kernel(x_ref, w_ref, cb_ref, sc_ref, o_ref, *, bits: int):
-    k = pl.program_id(3)
-    k_steps = pl.num_programs(3)
-
-    @pl.when(k == 0)
-    def _init():
-        o_ref[0] = jnp.zeros_like(o_ref[0])
-
-    w_idx = _unpack_natural(w_ref[0], bits)               # (bn, bk) int32
-    w_deq = jnp.take(cb_ref[...], w_idx)                  # codebook dequant
-    x = x_ref[0].astype(jnp.float32)                      # (bm, bk)
-    o_ref[0] += jax.lax.dot_general(
-        x, w_deq, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(k == k_steps - 1)
-    def _epilogue():
-        o_ref[0] = o_ref[0] * sc_ref[0][None, :]
-
-
-def _expert_grouped_kernel(x_ref, w_ref, cb_ref, sc_ref, o_ref, *, bits: int,
-                           group_size: int):
-    """Group-wise variant: k-position-dependent scales fold into the
-    dequantized tile before the contraction (no epilogue)."""
+def _expert_kernel(x_ref, w_ref, cb_ref, sc_ref, o_ref, *, bits: int,
+                   group_size: int | None):
+    """One expert's tile: the dense kernel's body (dequant_dot) on the
+    expert's slot-major activations; per-channel scales in the epilogue,
+    group-wise scales on the dequantized tile."""
     k = pl.program_id(3)
 
     @pl.when(k == 0)
     def _init():
         o_ref[0] = jnp.zeros_like(o_ref[0])
 
-    w_idx = _unpack_natural(w_ref[0], bits)               # (bn, bk) int32
-    w_deq = jnp.take(cb_ref[...], w_idx)
-    w_deq = w_deq * _expand_scales_tile(sc_ref[0], group_size)
-    x = x_ref[0].astype(jnp.float32)                      # (bm, bk)
-    o_ref[0] += jax.lax.dot_general(
-        x, w_deq, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    f = packing.PACK_FACTOR[bits]
+    bkp = w_ref.shape[-1]
+    scale = None
+    if group_size is not None:
+        scale = group_scale_tile(sc_ref[0], bkp, group_size // f)
+    o_ref[0] += dequant_dot([x_ref[0, i] for i in range(f)], w_ref[0],
+                            cb_ref, bits=bits, scale=scale)
+
+    if group_size is None:
+        @pl.when(k == pl.num_programs(3) - 1)
+        def _epilogue():
+            o_ref[0] = o_ref[0] * sc_ref[0]
 
 
 @functools.partial(
@@ -94,85 +84,70 @@ def expert_dequant_matmul_pallas(
     if grouped:
         assert group_size % f == 0 and K % group_size == 0, (K, group_size, f)
         assert scales.shape == (E, N, K // group_size), (scales.shape,)
-
-    bm, bn = _fit(bm, M), _fit(bn, N)
-    unit = group_size if grouped else f
-    bk = _fit(max(bk // unit, 1), K // unit) * unit
+    bm, bn, bk = matmul_blocks(M, N, K, bits=bits, group_size=group_size,
+                               bm=bm, bn=bn, bk=bk, scale_align=LANE)
     bkp = bk // f
 
-    grid = (E, M // bm, N // bn, K // bk)
     if grouped:
-        kernel = functools.partial(_expert_grouped_kernel, bits=bits,
-                                   group_size=group_size)
         scale_spec = pl.BlockSpec((1, bn, bk // group_size),
                                   lambda e, i, j, k: (e, j, k))
     else:
-        kernel = functools.partial(_expert_kernel, bits=bits)
-        scale_spec = pl.BlockSpec((1, bn), lambda e, i, j, k: (e, j))
+        scales = scales.reshape(E, 1, N)
+        scale_spec = pl.BlockSpec((1, 1, bn), lambda e, i, j, k: (e, 0, j))
     return pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_expert_kernel, bits=bits, group_size=group_size),
+        grid=(E, M // bm, N // bn, K // bk),
         in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda e, i, j, k: (e, i, k)),
+            pl.BlockSpec((1, f, bm, bkp), lambda e, i, j, k: (e, 0, i, k)),
             pl.BlockSpec((1, bn, bkp), lambda e, i, j, k: (e, j, k)),
-            pl.BlockSpec((codebook.shape[0],), lambda e, i, j, k: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             scale_spec,
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda e, i, j, k: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, M, N), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, w_packed, codebook.astype(jnp.float32), scales.astype(jnp.float32))
+    )(slot_major(x, f), w_packed, codebook.astype(jnp.float32),
+      scales.astype(jnp.float32))
 
 
 # --------------------------------------------------------------------------- #
 # Activation-quantized expert LUT GEMM (w{b}a{b} MoE path)
 # --------------------------------------------------------------------------- #
 
-def _expert_lut_kernel(a_ref, w_ref, lut_ref, o_ref, *, bits: int,
-                       scheme: str, lookup_impl: str):
+def _expert_lut_kernel(a_ref, w_ref, lut_ref, *refs, bits: int,
+                       group_size: int | None):
+    """One expert's tile: the dense LUT kernel's body (lut_gemm.lut_dot);
+    group scales arrive transposed, (E, K/G, N)."""
+    sc_ref, o_ref = refs if group_size is not None else (None, *refs)
     k = pl.program_id(3)
 
     @pl.when(k == 0)
     def _init():
         o_ref[0] = jnp.zeros_like(o_ref[0])
 
-    prods = _lut_products(a_ref[0], w_ref[0], lut_ref, bits=bits,
-                          a_bits=bits, scheme=scheme,
-                          lookup_impl=lookup_impl)
-    o_ref[0] += prods.sum(axis=-1).astype(jnp.float32)
-
-
-def _expert_lut_grouped_kernel(a_ref, w_ref, lut_ref, sc_ref, o_ref, *,
-                               bits: int, scheme: str, lookup_impl: str,
-                               group_size: int):
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _init():
-        o_ref[0] = jnp.zeros_like(o_ref[0])
-
-    prods = _lut_products(a_ref[0], w_ref[0], lut_ref, bits=bits,
-                          a_bits=bits, scheme=scheme,
-                          lookup_impl=lookup_impl)
-    bm, bn, bk = prods.shape
-    ng = bk // group_size
-    pg = prods.reshape(bm, bn, ng, group_size).sum(axis=-1)
-    sc = sc_ref[0]                                                # (bn, ng)
-    o_ref[0] += (pg * sc[None, :, :]).sum(axis=-1).astype(jnp.float32)
+    f = packing.PACK_FACTOR[bits]
+    a_slots = [a_ref[0, i] for i in range(f)]
+    if group_size is None:
+        o_ref[0] += lut_dot(a_slots, w_ref[0], lut_ref, bits=bits,
+                            a_bits=bits)[0]
+    else:
+        parts = lut_dot(a_slots, w_ref[0], lut_ref, bits=bits, a_bits=bits,
+                        n_groups=sc_ref.shape[1])
+        o_ref[0] += scaled_sum(parts, sc_ref[0])
 
 
 @functools.partial(
-    jax.jit, static_argnames=("bits", "scheme", "lookup_impl", "group_size",
-                              "bm", "bn", "bk", "interpret"))
+    jax.jit, static_argnames=("bits", "group_size", "bm", "bn", "bk",
+                              "interpret"))
 def expert_lut_gemm_pallas(
-    a_packed: jax.Array,     # (E, M, K/f) uint8 — packed per-expert act codes
+    a_idx: jax.Array,        # (E, M, K) uint8 per-expert activation codes
     w_packed: jax.Array,     # (E, N, K/f) uint8
     lut_table: jax.Array,    # (2^(2*bits),) product LUT (w_bits == a_bits)
     w_scales: jax.Array | None = None,   # (E, N, K/G) group-wise
     *,
     bits: int = 2,
-    scheme: str = "d",
-    lookup_impl: str = "take",
     group_size: int | None = None,
     bm: int = 128,
     bn: int = 128,
@@ -182,59 +157,45 @@ def expert_lut_gemm_pallas(
     """Per-expert LUT GEMM: out[e,m,n] = sum_k LUT[(w[e,n,k]<<b) | a[e,m,k]].
 
     The batched/grouped form of ``lut_gemm_pallas`` — the grid walks
-    (E, M-tiles, N-tiles, K-tiles) like ``expert_dequant_matmul_pallas`` but
-    the tile body is the multiply-free unpack/OR/lookup/accumulate loop.
-    Like ``lut_gemm``, per-channel weight scales stay in the caller's
-    epilogue; group-wise scales fuse into the K loop.
+    (E, M-tiles, N-tiles, K-tiles) like ``expert_dequant_matmul_pallas``
+    and the tile body is the dense kernel's multiply-free lookup. Like
+    ``lut_gemm``, per-channel weight scales stay in the caller's epilogue;
+    group-wise scales fuse into the K loop.
     """
     f = packing.PACK_FACTOR[bits]
-    E, M, Kp = a_packed.shape
-    E2, N, Kp2 = w_packed.shape
-    assert E == E2 and Kp == Kp2, (a_packed.shape, w_packed.shape)
-    K = Kp * f
+    E, M, K = a_idx.shape
+    E2, N, Kp = w_packed.shape
+    assert E == E2 and Kp * f == K, (a_idx.shape, w_packed.shape)
     grouped = w_scales is not None
     if grouped:
         assert group_size is not None and group_size % f == 0 \
             and K % group_size == 0, (K, group_size, f)
         assert w_scales.shape == (E, N, K // group_size), (w_scales.shape,)
-
-    bm, bn = _fit(bm, M), _fit(bn, N)
-    unit = group_size if grouped else f
-    u = _fit(max(bk // unit, 1), K // unit)
-    cap = 8 * 1024 * 1024
-    while bm * bn * (u * unit) * 8 > cap and u > 1:
-        u = _fit(max(u // 2, 1), K // unit)
-    while bm * bn * (u * unit) * 8 > cap and (bm > 8 or bn > 8):
-        if bm >= bn and bm > 8:
-            bm = _fit(max(bm // 2, 1), M)
-        else:
-            bn = _fit(max(bn // 2, 1), N)
-    bk = u * unit
+    else:
+        group_size = None
+    bm, bn, bk = matmul_blocks(M, N, K, bits=bits, group_size=group_size,
+                               bm=bm, bn=bn, bk=bk, a_bits=bits)
     bkp = bk // f
 
-    grid = (E, M // bm, N // bn, Kp // bkp)
     in_specs = [
-        pl.BlockSpec((1, bm, bkp), lambda e, i, j, k: (e, i, k)),
+        pl.BlockSpec((1, f, bm, bkp), lambda e, i, j, k: (e, 0, i, k)),
         pl.BlockSpec((1, bn, bkp), lambda e, i, j, k: (e, j, k)),
-        pl.BlockSpec((lut_table.shape[0],), lambda e, i, j, k: (0,)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
-    args = [a_packed, w_packed, lut_table.astype(jnp.float32)]
+    args = [slot_major(a_idx.astype(jnp.uint8), f), w_packed,
+            lut_table.astype(jnp.float32)]
     if grouped:
-        in_specs.append(pl.BlockSpec((1, bn, bk // group_size),
-                                     lambda e, i, j, k: (e, j, k)))
-        args.append(w_scales.astype(jnp.float32))
-        kernel = functools.partial(
-            _expert_lut_grouped_kernel, bits=bits, scheme=scheme,
-            lookup_impl=lookup_impl, group_size=group_size)
-    else:
-        kernel = functools.partial(
-            _expert_lut_kernel, bits=bits, scheme=scheme,
-            lookup_impl=lookup_impl)
+        in_specs.append(pl.BlockSpec((1, bk // group_size, bn),
+                                     lambda e, i, j, k: (e, k, j)))
+        args.append(jnp.swapaxes(w_scales.astype(jnp.float32), 1, 2))
     return pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_expert_lut_kernel, bits=bits,
+                          group_size=group_size),
+        grid=(E, M // bm, N // bn, K // bk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bm, bn), lambda e, i, j, k: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, M, N), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)

@@ -27,12 +27,14 @@ tiles — the GEMV specialization.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import packing
+from .lut_gemm import LANE, SUBLANE, _fit, _shrink
 
 GEMV_ROWS = 4  # M <= GEMV_ROWS routes to the decode (GEMV) tiling
 
@@ -140,13 +142,6 @@ def _bs_grouped_kernel(a_ref, w_ref, sc_ref, o_ref, *, bits, group, a_bits,
     o_ref[...] += (acc.astype(jnp.float32) * sc[None, :, :]).sum(-1)
 
 
-def _fit(target: int, n: int) -> int:
-    b = max(1, min(target, n))
-    while n % b:
-        b -= 1
-    return b
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("bits", "a_bits", "group", "group_size", "lookup_impl",
@@ -181,18 +176,22 @@ def lut_gemm_bitsliced_pallas(
             and K % group_size == 0, (K, group_size, group)
 
     gemv = M <= GEMV_ROWS
-    bm = M if gemv else _fit(bm, M)
-    bn = _fit(bn, N)
-    unit = group_size if grouped else group
-    u = _fit(max(bk // unit, 1), K // unit)
+    bm = M if gemv else _fit(bm, M, SUBLANE)
+    bn = _fit(bn, N, LANE)
+    # a K step spans whole lane tiles of the pattern (and group-scale)
+    # blocks, or the whole row
+    k_align = math.lcm(LANE * group, LANE * group_size if grouped else 1)
+    bk = _fit(bk, K, k_align)
     cap = 8 * 1024 * 1024
     # VMEM working set ~ the (bm, bn, bk/g) int32 gather tile + the LUT.
-    tile_bytes = lambda uu: bm * bn * (uu * unit // group) * 8  # noqa: E731
-    while tile_bytes(u) > cap and u > 1:
-        u = _fit(max(u // 2, 1), K // unit)
-    while tile_bytes(u) > cap and bn > 8:
-        bn = _fit(max(bn // 2, 1), N)
-    bk = u * unit
+    while bm * bn * (bk // group) * 8 > cap:
+        nbk, nbn = _shrink(bk, K, k_align), _shrink(bn, N, LANE)
+        if nbk < bk:
+            bk = nbk
+        elif nbn < bn:
+            bn = nbn
+        else:
+            break
     bkg = bk // group
 
     if gemv:
@@ -326,14 +325,14 @@ def lut_gemm_bs_fused_pallas(
             (K, group_size, group)
 
     gemv = M <= GEMV_ROWS
-    bm = M if gemv else _fit(bm, M)
-    bn = _fit(bn, N)
+    bm = M if gemv else _fit(bm, M, SUBLANE)
+    bn = _fit(bn, N, LANE)
     bkg = K // group
     cap = 8 * 1024 * 1024
     # VMEM working set ~ the (bm, bn, bkg) int32 gather tile (+ the paired
     # 2^(2g)-entry LUT, bm * bkg * 2^(2g) int16).
-    while bm * bn * bkg * 8 > cap and bn > 8:
-        bn = _fit(max(bn // 2, 1), N)
+    while bm * bn * bkg * 8 > cap and _shrink(bn, N, LANE) < bn:
+        bn = _shrink(bn, N, LANE)
 
     scv = w_scales.astype(jnp.float32)
     if not grouped:

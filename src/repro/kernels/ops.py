@@ -10,7 +10,7 @@ distance from the offending call.
 Every call site is one mechanical rewrite away::
 
     from repro.kernels import registry as kr
-    kr.dispatch("lut_gemm", a_packed, w_packed, lut.table, w_scales,
+    kr.dispatch("lut_gemm", a_idx, w_packed, lut.table, w_scales,
                 w_bits=..., a_bits=..., backend=..., tp=...)
 
 Dispatch counters moved to ``repro.obs.metrics``: ``scoped()`` for isolated
@@ -21,7 +21,7 @@ from __future__ import annotations
 
 # old name -> replacement spelling, shown verbatim in the error message
 _REMOVED = {
-    "lut_gemm": 'registry.dispatch("lut_gemm", a_packed, w_packed, '
+    "lut_gemm": 'registry.dispatch("lut_gemm", a_idx, w_packed, '
                 "lut.table, w_scales, w_bits=..., a_bits=..., ...)",
     "dequant_matmul": 'registry.dispatch("dequant_matmul", a, w_packed, '
                       "codebook, scales, bits=..., ...)",
@@ -29,7 +29,7 @@ _REMOVED = {
                    'table, backend="ref")',
     "expert_dequant_matmul": 'registry.dispatch("expert_dequant_matmul", '
                              "x, w_packed, codebook, scales, bits=..., ...)",
-    "expert_lut_gemm": 'registry.dispatch("expert_lut_gemm", a_packed, '
+    "expert_lut_gemm": 'registry.dispatch("expert_lut_gemm", a_idx, '
                        "w_packed, lut.table, w_scales, w_bits=..., ...)",
     "kv_cache_attention": 'registry.dispatch("kv_cache_attention", q, '
                           "k_packed, k_sc, v_packed, v_sc, lengths, ...)",

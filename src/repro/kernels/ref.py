@@ -21,8 +21,8 @@ from repro.core.lut import ProductLUT
 
 
 def ref_lut_gemm(
-    a_packed: jax.Array,
-    w_packed: jax.Array,
+    a_idx: jax.Array,        # (M, K) activation codes
+    w_packed: jax.Array,     # (N, K/f) packed weight codes
     lut: ProductLUT,
     w_scales: jax.Array | None = None,
     group_size: int | None = None,
@@ -33,7 +33,7 @@ def ref_lut_gemm(
     With group-wise weight scales (w_scales (N, K/G), group_size G), each
     K-group's partial sum is scaled before accumulation:
     out[m, n] = sum_g s[n, g] * sum_{k in g} lut[...]."""
-    a_idx = packing.unpack(a_packed, lut.a_bits).astype(jnp.int32)  # (M, K)
+    a_idx = a_idx.astype(jnp.int32)
     w_idx = packing.unpack(w_packed, lut.w_bits).astype(jnp.int32)  # (N, K)
     idx = (w_idx[None, :, :] << lut.a_bits) | a_idx[:, None, :]      # (M, N, K)
     prods = jnp.take(lut.table, idx)                                  # (M, N, K)
@@ -45,19 +45,17 @@ def ref_lut_gemm(
 
 
 def ref_dequant_gemm(
-    a_packed: jax.Array,
+    a_idx: jax.Array,        # (M, K) activation codes
     w_packed: jax.Array,
     w_levels: jax.Array,
     a_levels: jax.Array,
     w_bits: int,
-    a_bits: int,
 ) -> jax.Array:
     """Equivalent computation via explicit dequantize-then-matmul. Must equal
     ref_lut_gemm exactly when products are exactly representable (property
     test)."""
-    a_idx = packing.unpack(a_packed, a_bits).astype(jnp.int32)
     w_idx = packing.unpack(w_packed, w_bits).astype(jnp.int32)
-    a_deq = jnp.take(a_levels, a_idx)  # (M, K)
+    a_deq = jnp.take(a_levels, a_idx.astype(jnp.int32))  # (M, K)
     w_deq = jnp.take(w_levels, w_idx)  # (N, K)
     # Same reduction structure as ref_lut_gemm (elementwise products, sum over
     # K last) so the comparison is exact, not just close.
@@ -306,7 +304,7 @@ def ref_expert_dequant_matmul(
 
 
 def ref_expert_lut_gemm(
-    a_packed: jax.Array,     # (E, M, K/fa) packed per-expert activation codes
+    a_idx: jax.Array,        # (E, M, K) per-expert activation codes
     w_packed: jax.Array,     # (E, N, K/fw)
     lut: ProductLUT,
     w_scales: jax.Array | None = None,   # (E, N, K/G) group-wise
@@ -316,10 +314,10 @@ def ref_expert_lut_gemm(
     expert axis. out[e, m, n] = sum_k lut[w_idx[e,n,k] << a_bits | a_idx[e,m,k]]
     (per K-group scaled before accumulation when ``w_scales`` is given)."""
     if w_scales is None:
-        return jax.vmap(lambda a, w: ref_lut_gemm(a, w, lut))(a_packed, w_packed)
+        return jax.vmap(lambda a, w: ref_lut_gemm(a, w, lut))(a_idx, w_packed)
     return jax.vmap(lambda a, w, s: ref_lut_gemm(
         a, w, lut, w_scales=s, group_size=group_size))(
-            a_packed, w_packed, w_scales)
+            a_idx, w_packed, w_scales)
 
 
 def ref_kv_cache_attention(

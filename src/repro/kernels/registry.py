@@ -163,7 +163,7 @@ def dispatch(
                 fn = lambda *xs: jax.lax.psum(compute(*xs), ax)  # noqa: E731
             return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                                  out_specs=out_spec,
-                                 check_rep=False)(*present)
+                                 check_vma=False)(*present)
     return compute(*present)
 
 
@@ -173,10 +173,10 @@ def dispatch(
 # --------------------------------------------------------------------------- #
 
 def _lut_gemm_tp(role, ax, n, arrays, static):
-    a_packed, w_packed, _table, sc = arrays
+    a_idx, w_packed, _table, sc = arrays
     N, Kp = w_packed.shape
     ok = (N % n == 0 if role == "col"
-          else Kp % n == 0 and a_packed.shape[-1] % n == 0)
+          else Kp % n == 0 and a_idx.shape[-1] % n == 0)
     if static.get("group_size") is not None and sc is not None:
         ok = ok and (sc.shape[-1] % n == 0 or role == "col")
     if not ok:
@@ -224,10 +224,10 @@ def _expert_dequant_matmul_tp(role, ax, n, arrays, static):
 
 
 def _expert_lut_gemm_tp(role, ax, n, arrays, static):
-    a_packed, w_packed, _table, sc = arrays
+    a_idx, w_packed, _table, sc = arrays
     _, N, Kp = w_packed.shape
     ok = (N % n == 0 if role == "col"
-          else Kp % n == 0 and a_packed.shape[-1] % n == 0
+          else Kp % n == 0 and a_idx.shape[-1] % n == 0
           and (sc is None or sc.shape[-1] % n == 0))
     if not ok:
         return None
@@ -298,17 +298,14 @@ def _bs_fused_tile_space(m, k, n, static):
 # Impl adapters: registry positional arity -> each kernel's own signature
 # --------------------------------------------------------------------------- #
 
-def _lut_gemm_ref(ap, wp, table, sc, *, w_bits, a_bits, scheme="d",
-                  lookup_impl="take", group_size=None):
-    del scheme, lookup_impl
-    return _ref.ref_lut_gemm(ap, wp, ProductLUT(table, w_bits, a_bits),
+def _lut_gemm_ref(a_idx, wp, table, sc, *, w_bits, a_bits, group_size=None):
+    return _ref.ref_lut_gemm(a_idx, wp, ProductLUT(table, w_bits, a_bits),
                              w_scales=sc, group_size=group_size)
 
 
-def _lut_gemm_pl(ap, wp, table, sc, *, w_bits, a_bits, scheme="d",
-                 lookup_impl="take", group_size=None, interpret=False, **blk):
-    return lut_gemm_pallas(ap, wp, table, sc, bits=w_bits, a_bits=a_bits,
-                           scheme=scheme, lookup_impl=lookup_impl,
+def _lut_gemm_pl(a_idx, wp, table, sc, *, w_bits, a_bits, group_size=None,
+                 interpret=False, **blk):
+    return lut_gemm_pallas(a_idx, wp, table, sc, bits=w_bits, a_bits=a_bits,
                            group_size=group_size, interpret=interpret, **blk)
 
 
@@ -374,20 +371,17 @@ def _expert_dequant_pl(x, wp, cb, sc, *, bits, group_size=None,
                                         interpret=interpret, **blk)
 
 
-def _expert_lut_ref(ap, wp, table, sc, *, w_bits, a_bits, scheme="d",
-                    lookup_impl="take", group_size=None):
-    del scheme, lookup_impl
-    return _ref.ref_expert_lut_gemm(ap, wp,
+def _expert_lut_ref(a_idx, wp, table, sc, *, w_bits, a_bits,
+                    group_size=None):
+    return _ref.ref_expert_lut_gemm(a_idx, wp,
                                     ProductLUT(table, w_bits, a_bits),
                                     w_scales=sc, group_size=group_size)
 
 
-def _expert_lut_pl(ap, wp, table, sc, *, w_bits, a_bits, scheme="d",
-                   lookup_impl="take", group_size=None, interpret=False,
-                   **blk):
+def _expert_lut_pl(a_idx, wp, table, sc, *, w_bits, a_bits, group_size=None,
+                   interpret=False, **blk):
     del a_bits
-    return expert_lut_gemm_pallas(ap, wp, table, sc, bits=w_bits,
-                                  scheme=scheme, lookup_impl=lookup_impl,
+    return expert_lut_gemm_pallas(a_idx, wp, table, sc, bits=w_bits,
                                   group_size=group_size, interpret=interpret,
                                   **blk)
 
@@ -464,7 +458,8 @@ register(KernelOp(
     tile_space=_matmul_tile_space,
     doc="Paper-faithful product-LUT GEMM: "
         "out[m,n] = sum_k LUT[(w[n,k]<<b)|a[m,k]]. "
-        "arrays: (a_packed, w_packed, lut_table, w_scales|None)"))
+        "arrays: (a_idx, w_packed, lut_table, w_scales|None), a_idx the "
+        "(M, K) uint8 activation codes"))
 
 register(KernelOp(
     name="lut_gemm_bitsliced",
@@ -504,7 +499,7 @@ register(KernelOp(
     ref=_expert_lut_ref, pallas=_expert_lut_pl, tp_rule=_expert_lut_gemm_tp,
     tile_space=_matmul_tile_space,
     doc="Activation-quantized per-expert LUT GEMM (paper-faithful w{b}a{b} "
-        "MoE path). arrays: (a_packed, w_packed, lut_table, w_scales|None)"))
+        "MoE path). arrays: (a_idx, w_packed, lut_table, w_scales|None)"))
 
 register(KernelOp(
     name="lut65k_gemm",

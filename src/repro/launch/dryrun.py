@@ -192,7 +192,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     t_compile = time.time() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    cost = RL.xla_cost(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     stats = RL.parse_hlo(hlo, bf16_model=(meta["cfg"].dtype == "bfloat16"))
     rl = RL.roofline(stats, meta["cfg"], meta["shape"], n_dev,

@@ -13,19 +13,12 @@ Production target: TPU v5e pods, 256 chips each.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def _mesh(shape: tuple, axes: tuple) -> Mesh:
-    # jax >= 0.5 takes explicit axis types (we want Auto everywhere so GSPMD
-    # propagates through un-annotated ops); jax 0.4.x has neither the
-    # AxisType enum nor the kwarg and defaults to the same behaviour.
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except (ImportError, TypeError):
-        return jax.make_mesh(shape, axes)
+    # Auto axes everywhere so GSPMD propagates through un-annotated ops
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

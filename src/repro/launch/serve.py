@@ -44,7 +44,7 @@ from repro.configs import get_config, reduce_for_smoke
 from repro.core.qlinear import QuantPolicy
 from repro.core.qplan import PLANS, get_plan, make_plan
 from repro.models import lm, frontends
-from repro.launch import steps as St
+from repro.launch import compile_cache, steps as St
 from repro.launch.mesh import make_tp_mesh
 from repro.obs import Tracer, metrics as obs_metrics
 from repro.serving import Engine, Request, SamplerConfig
@@ -174,14 +174,13 @@ def validate_args(args, cfg) -> None:
                 "device_count=N before starting)")
 
 
-def serve_paged(cfg, qparams, args, mesh=None, spec=None) -> int:
-    """Continuous-batching serve loop over the paged engine. ``spec`` is an
-    optional (draft_cfg, draft_params) pair enabling self-speculative
-    decoding (--spec-draft-plan)."""
-    key = jax.random.PRNGKey(args.seed)
+def make_engine(cfg, qparams, args, mesh=None, spec=None) -> Engine:
+    """The paged engine as ``--paged`` serves with it (chip_smoke.py builds
+    its engines here too). ``spec`` is an optional (draft_cfg,
+    draft_params) pair enabling self-speculative decoding
+    (--spec-draft-plan)."""
     max_len = args.prompt_len + args.gen + args.block_size
     max_len = -(-max_len // args.block_size) * args.block_size
-    tracer = Tracer() if args.trace_out else None
     sampler = SamplerConfig(temperature=args.temperature, top_k=args.top_k,
                             top_p=args.top_p, seed=args.seed)
     spec_kw = {}
@@ -189,13 +188,21 @@ def serve_paged(cfg, qparams, args, mesh=None, spec=None) -> int:
         dcfg, dparams = spec
         spec_kw = dict(spec_draft_params=dparams, spec_draft_cfg=dcfg,
                        spec_k=args.spec_k)
-    engine = Engine(cfg, qparams, n_slots=args.batch, max_len=max_len,
-                    block_size=args.block_size, max_queue=args.max_queue,
-                    prefill=args.prefill,
-                    prefix_cache=args.prefix_cache,
-                    prefill_batch=args.prefill_batch, mesh=mesh,
-                    sampler=sampler, tracer=tracer, ring=args.ring,
-                    kv_splits=args.kv_splits, **spec_kw)
+    return Engine(cfg, qparams, n_slots=args.batch, max_len=max_len,
+                  block_size=args.block_size, max_queue=args.max_queue,
+                  prefill=args.prefill,
+                  prefix_cache=args.prefix_cache,
+                  prefill_batch=args.prefill_batch, mesh=mesh,
+                  sampler=sampler,
+                  tracer=Tracer() if args.trace_out else None,
+                  ring=args.ring, kv_splits=args.kv_splits, **spec_kw)
+
+
+def serve_paged(cfg, qparams, args, mesh=None, spec=None) -> int:
+    """Continuous-batching serve loop over the paged engine (make_engine)."""
+    key = jax.random.PRNGKey(args.seed)
+    engine = make_engine(cfg, qparams, args, mesh=mesh, spec=spec)
+    tracer = engine.tracer
     if mesh is not None:
         print(f"  tensor-parallel over {mesh.shape['model']} devices: "
               f"{engine.per_device_weight_bytes()/1e3:.1f} KB weights "
@@ -280,7 +287,7 @@ def serve_paged(cfg, qparams, args, mesh=None, spec=None) -> int:
     return 0
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -365,15 +372,12 @@ def main():
                          "--calib-batches sample batches")
     ap.add_argument("--calib-batches", type=int, default=4,
                     help="sample batches for --a-scale static calibration")
-    args = ap.parse_args()
+    return ap
 
-    cfg = get_config(args.arch)
-    if args.smoke:
-        cfg = reduce_for_smoke(cfg)
-    try:
-        validate_args(args, cfg)
-    except ValueError as e:
-        ap.error(str(e))
+
+def plan_for(args):
+    """(quant config, description) the flags select: --plan legacy, a named
+    preset, or make_plan over --w-bits/--a-bits/--group-size."""
     if args.plan == "legacy":
         quant = QuantPolicy(w_bits=args.w_bits, nonuniform=args.nonuniform)
         desc = f"legacy w{args.w_bits} (dequant-einsum)"
@@ -396,6 +400,21 @@ def main():
         g = f" g{args.group_size}" if args.group_size else ""
         s = " static-a" if args.a_scale == "static" else ""
         desc = f"plan w{args.w_bits}{a}{g}{s}"
+    return quant, desc
+
+
+def main():
+    ap = build_parser()
+    args = ap.parse_args()
+    cfg = get_config(args.arch)
+    if args.smoke:
+        cfg = reduce_for_smoke(cfg)
+    try:
+        validate_args(args, cfg)
+    except ValueError as e:
+        ap.error(str(e))
+    compile_cache.enable()
+    quant, desc = plan_for(args)
     cfg = dataclasses.replace(cfg, quant=quant)
 
     key = jax.random.PRNGKey(args.seed)
@@ -416,9 +435,10 @@ def main():
 
     t0 = time.time()
     obs_metrics.global_registry().clear(obs_metrics.KERNEL_DISPATCH)
-    qparams = jax.jit(lambda p: lm.quantize_tree(
-        p, cfg, tp=args.tp, act_scales=act_scales))(params)
-    qparams = jax.block_until_ready(qparams)
+    # packed eagerly, not under jit: a plan with ``tune`` times its tile
+    # candidates here, which needs real kernel runs, not a trace
+    qparams = jax.block_until_ready(lm.quantize_tree(
+        params, cfg, tp=args.tp, act_scales=act_scales))
     bf16_bytes = sum(x.size * 2 for x in jax.tree.leaves(params))
     q_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(qparams))
     print(f"  packed in {time.time()-t0:.2f}s: {bf16_bytes/1e6:.1f} MB bf16 "
@@ -431,8 +451,8 @@ def main():
             dcfg = dataclasses.replace(cfg,
                                        quant=get_plan(args.spec_draft_plan))
             t0 = time.time()
-            dparams = jax.block_until_ready(jax.jit(
-                lambda p: lm.quantize_tree(p, dcfg, tp=args.tp))(params))
+            dparams = jax.block_until_ready(
+                lm.quantize_tree(params, dcfg, tp=args.tp))
             d_bytes = sum(x.size * x.dtype.itemsize
                           for x in jax.tree.leaves(dparams))
             print(f"  drafter packed under plan '{args.spec_draft_plan}' "

@@ -141,7 +141,7 @@ def make_dp_train_step(cfg, optimizer: optim.Optimizer, mesh, *,
         step, mesh=mesh,
         in_specs=(state_spec, P(axis)),
         out_specs=(state_spec, rep),
-        check_rep=False)
+        check_vma=False)
     return sharded
 
 

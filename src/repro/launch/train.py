@@ -25,7 +25,7 @@ from repro.configs import SHAPES, ShapeConfig, get_config, reduce_for_smoke
 from repro.core.qlinear import QuantPolicy
 from repro.data import make_pipeline
 from repro.dist.fault import FaultConfig, run_resilient
-from repro.launch import steps as St
+from repro.launch import compile_cache, steps as St
 
 
 def build(args):
@@ -70,6 +70,7 @@ def main():
                     help="int8 error-feedback gradient all-reduce over the "
                          "data axis (dist.collectives.compressed_psum)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg, shape, opt = build(args)
     print(f"[train] {cfg.name}: {cfg.n_params()/1e6:.1f}M params, "

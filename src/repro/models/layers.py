@@ -771,12 +771,11 @@ def _expert_matmul(qw: QuantizedWeight, x: jax.Array, backend: str) -> jax.Array
                            preferred_element_type=jnp.float32)
             return y * a_scale if G is not None \
                 else y * qw.scales[:, None, :] * a_scale
-        ap = packing.pack(a_idx, qw.a_bits)
         y = kreg.dispatch(
-            "expert_lut_gemm", ap, qw.packed, qw.plut,
+            "expert_lut_gemm", a_idx, qw.packed, qw.plut,
             qw.scales if G is not None else None,
-            w_bits=qw.bits, a_bits=qw.a_bits, scheme=qw.scheme,
-            group_size=G, backend=backend, tp=qw.tp)
+            w_bits=qw.bits, a_bits=qw.a_bits, group_size=G,
+            backend=backend, tp=qw.tp)
         return y * a_scale if G is not None \
             else y * qw.scales[:, None, :] * a_scale
     return kreg.dispatch(

@@ -464,12 +464,16 @@ class Engine:
         self.slots = [_Slot() for _ in range(n_slots)]
         self.queue: deque[Request] = deque()
 
-        self._decode = jax.jit(self._decode_fn, donate_argnums=(0,))
-        self._prefill_chunk = jax.jit(self._prefill_fn, donate_argnums=(0,))
+        # parameters are an ARGUMENT of every step function (argument 0,
+        # caches argument 1): closed over, they would be baked into each
+        # executable as constants (GBs at published widths, minutes to
+        # compile)
+        self._decode = jax.jit(self._decode_fn, donate_argnums=(1,))
+        self._prefill_chunk = jax.jit(self._prefill_fn, donate_argnums=(1,))
         self._prefill_batched = jax.jit(self._prefill_batched_fn,
-                                        donate_argnums=(0,))
+                                        donate_argnums=(1,))
         self._prefill_whole = jax.jit(self._prefill_whole_fn,
-                                      donate_argnums=(0,))
+                                      donate_argnums=(1,))
         # partial() gives each engine its own jit wrapper over the
         # module-level reset_slot: jitting C.reset_slot directly shares one
         # pjit cache across every engine in the process, so n_compiles()
@@ -478,10 +482,10 @@ class Engine:
                               donate_argnums=(0,))
         self._sample = jax.jit(self._sample_fn)
         if self.spec:
-            self._draft = jax.jit(self._draft_fn, donate_argnums=(0,))
-            self._verify = jax.jit(self._verify_fn, donate_argnums=(0,))
+            self._draft = jax.jit(self._draft_fn, donate_argnums=(1,))
+            self._verify = jax.jit(self._verify_fn, donate_argnums=(1,))
             self._draft_prefill = jax.jit(self._draft_prefill_fn,
-                                          donate_argnums=(0,))
+                                          donate_argnums=(1,))
             self._spec_accept = jax.jit(self._spec_accept_fn)
 
         # observability: a per-engine metrics registry backs every counter
@@ -544,15 +548,12 @@ class Engine:
         in ``obs``, a ``compile:<fn>`` sub-slice in the step timeline). The
         call runs with ``obs`` pushed as a metrics scope so trace-time
         kernel dispatch counters land in this engine's snapshot too."""
-        try:
-            before = int(fn._cache_size())
-        except AttributeError:
-            before = None
+        before = fn._cache_size()
         tr = self.tracer
         t0 = tr.now() if tr is not None else time.perf_counter()
         with obs_metrics.scoped(registry=self.obs):
             out = fn(*args)
-        if before is not None and int(fn._cache_size()) > before:
+        if fn._cache_size() > before:
             t1 = tr.now() if tr is not None else time.perf_counter()
             self.obs.inc("jit_compiles_total", fn=name)
             self.obs.observe("jit_compile_s", t1 - t0, fn=name)
@@ -591,7 +592,7 @@ class Engine:
             lambda x, s: jax.lax.with_sharding_constraint(x, s),
             tree, self._draft_cache_specs)
 
-    def _decode_fn(self, caches, tables, rings, tokens, pos, active):
+    def _decode_fn(self, params, caches, tables, rings, tokens, pos, active):
         """One token for every slot. tokens (n_slots, 1) int32, pos
         (n_slots,) int32, tables (n_slots, nb_max) int32, rings
         (n_slots, ring_len) int32 or None (static per engine), active
@@ -599,16 +600,16 @@ class Engine:
         logits). kv_splits is a static engine constant: the decode-shaped
         forward walks the KV in chunks when it resolves above 1."""
         with self._mesh_ctx():
-            h, new = lm.forward(self.params, self.cfg, tokens, caches=caches,
+            h, new = lm.forward(params, self.cfg, tokens, caches=caches,
                                 pos=pos, block_tables=tables,
                                 ring_tables=rings,
                                 kv_splits=self.kv_splits)
             # inactive / prefilling slots keep their per-slot recurrent state
             new = C.select_slots(caches, new, active)
-            logits = lm.logits_fn(self.params, self.cfg, h)[:, -1]
+            logits = lm.logits_fn(params, self.cfg, h)[:, -1]
             return self._constrain_caches(new), logits
 
-    def _prefill_fn(self, caches, table_row, ring_row, tokens, start,
+    def _prefill_fn(self, params, caches, table_row, ring_row, tokens, start,
                     slot_ix):
         """One prompt chunk for one request. tokens (1, chunk) int32 (pad
         rows zero), start scalar int32 (first row index), slot_ix scalar
@@ -616,20 +617,21 @@ class Engine:
         null block; per-slot state is sliced/merged around the forward."""
         with self._mesh_ctx():
             sliced = C.slot_slice(caches, slot_ix)
-            _, new = lm.forward(self.params, self.cfg, tokens, caches=sliced,
+            _, new = lm.forward(params, self.cfg, tokens, caches=sliced,
                                 pos=start[None], block_tables=table_row[None],
                                 ring_tables=(None if ring_row is None
                                              else ring_row[None]))
             return self._constrain_caches(C.slot_merge(caches, new, slot_ix))
 
-    def _prefill_batched_fn(self, caches, tables, rings, tokens, starts):
+    def _prefill_batched_fn(self, params, caches, tables, rings, tokens,
+                            starts):
         """Fixed-shape multi-request chunk. tokens (prefill_batch, chunk)
         int32, starts (prefill_batch,) int32, tables (prefill_batch, nb_max)
         int32. Pad rows carry an all-null table (writes land in the null
         block, outputs discarded). Only valid for archs without per-slot
         state, so the returned tree is the updated pool wholesale."""
         with self._mesh_ctx():
-            _, new = lm.forward(self.params, self.cfg, tokens, caches=caches,
+            _, new = lm.forward(params, self.cfg, tokens, caches=caches,
                                 pos=starts, block_tables=tables,
                                 ring_tables=rings)
             return self._constrain_caches(new)
@@ -642,8 +644,8 @@ class Engine:
             return S.sample(logits, self.sampler, uids, sidx, temperature,
                             top_p)
 
-    def _draft_fn(self, dcaches, tables, rings, first_tok, pos, uids, sidx,
-                  temperature, top_p):
+    def _draft_fn(self, dparams, dcaches, tables, rings, first_tok, pos, uids,
+                  sidx, temperature, top_p):
         """spec_k+1 drafter steps (lax.scan over one-token forwards against
         the DRAFT cache tree) writing rows pos..pos+spec_k. The scan feeds
         [F[pos], d_1..d_k] — one step more than it samples — so a fully
@@ -658,12 +660,11 @@ class Engine:
         with self._mesh_ctx():
             def one(carry, i):
                 caches, tok = carry
-                h, new = lm.forward(self.draft_params, self.draft_cfg,
+                h, new = lm.forward(dparams, self.draft_cfg,
                                     tok[:, None], caches=caches, pos=pos + i,
                                     block_tables=tables, ring_tables=rings,
                                     kv_splits=self.kv_splits)
-                logits = lm.logits_fn(self.draft_params, self.draft_cfg,
-                                      h)[:, -1]
+                logits = lm.logits_fn(dparams, self.draft_cfg, h)[:, -1]
                 p = S.probs(logits, temperature, self.sampler.top_k, top_p)
                 keys = jax.vmap(jax.random.fold_in, (0, None))(base, i)
                 d = S.draw(p, keys)
@@ -673,7 +674,7 @@ class Engine:
         k = self.spec_k
         return dcaches, ds[:k].T, jnp.moveaxis(ps[:k], 0, 1)
 
-    def _verify_fn(self, caches, tables, rings, tokens, pos, active):
+    def _verify_fn(self, params, caches, tables, rings, tokens, pos, active):
         """Fixed-shape (n_slots, spec_k+1) TARGET forward over
         [F[pos], d_1..d_k] returning logits at EVERY position — the same
         per-row chunk math as _prefill_batched_fn, just with the hidden
@@ -682,20 +683,21 @@ class Engine:
         rewritten by the next round's forward before any emitted query
         attends them (the engine advances pos only over emitted tokens)."""
         with self._mesh_ctx():
-            h, new = lm.forward(self.params, self.cfg, tokens, caches=caches,
+            h, new = lm.forward(params, self.cfg, tokens, caches=caches,
                                 pos=pos, block_tables=tables,
                                 ring_tables=rings)
             new = C.select_slots(caches, new, active)
-            logits = lm.logits_fn(self.params, self.cfg, h)
+            logits = lm.logits_fn(params, self.cfg, h)
             return self._constrain_caches(new), logits
 
-    def _draft_prefill_fn(self, dcaches, tables, rings, tokens, starts):
+    def _draft_prefill_fn(self, dparams, dcaches, tables, rings, tokens,
+                          starts):
         """_prefill_batched_fn over the DRAFTER params/cache tree: replays
         chunks of the fed-token stream to catch the drafter's KV up to the
         target's context (after admission, radix full-prefix hits,
         preemption-requeue, or a drafter-KV eviction)."""
         with self._mesh_ctx():
-            _, new = lm.forward(self.draft_params, self.draft_cfg, tokens,
+            _, new = lm.forward(dparams, self.draft_cfg, tokens,
                                 caches=dcaches, pos=starts,
                                 block_tables=tables, ring_tables=rings)
             return self._constrain_draft(new)
@@ -717,13 +719,13 @@ class Engine:
                                 S.fold_tag(keys, S.TAG_ACCEPT),
                                 S.fold_tag(keys, S.TAG_RESAMPLE))
 
-    def _prefill_whole_fn(self, caches, table_row, ring_row, prompt,
+    def _prefill_whole_fn(self, params, caches, table_row, ring_row, prompt,
                           slot_ix):
         # legacy-equivalent admission: one full-prompt forward (same math,
         # same float path as the dense batcher), rows scattered into blocks
         # (local layers scatter into the slot's ring when ring-paging is on)
         with self._mesh_ctx():
-            _, pf = lm.forward(self.params, self.cfg, prompt,
+            _, pf = lm.forward(params, self.cfg, prompt,
                                collect_cache=True)
             return self._constrain_caches(
                 C.write_prompt_rows(caches, pf, table_row, slot_ix,
@@ -978,7 +980,7 @@ class Engine:
         t0 = tr.now() if tr is not None else 0.0
         self.caches = self._run_jit(
             "prefill_whole", self._prefill_whole,
-            self.caches, jnp.asarray(self._table_row(s)),
+            self.params, self.caches, jnp.asarray(self._table_row(s)),
             self._ring_row(s.ring_blocks),
             jnp.asarray(s.prompt, jnp.int32)[None],
             jnp.asarray(ix, jnp.int32))
@@ -1041,7 +1043,7 @@ class Engine:
         t0 = tr.now() if tr is not None else 0.0
         self.caches = self._run_jit(
             "prefill_chunk", self._prefill_chunk,
-            self.caches, jnp.asarray(self._table_row(s)),
+            self.params, self.caches, jnp.asarray(self._table_row(s)),
             self._ring_row(s.ring_blocks), jnp.asarray(chunk)[None],
             jnp.asarray(start, jnp.int32), jnp.asarray(ix, jnp.int32))
         if tr is not None:
@@ -1083,7 +1085,7 @@ class Engine:
         t0 = tr.now() if tr is not None else 0.0
         self.caches = self._run_jit(
             "prefill_batched", self._prefill_batched,
-            self.caches, jnp.asarray(tables),
+            self.params, self.caches, jnp.asarray(tables),
             self._ring_rows([(j, ix) for j, (ix, _) in enumerate(live)], Bp),
             jnp.asarray(tokens), jnp.asarray(starts))
         if tr is not None:
@@ -1144,7 +1146,7 @@ class Engine:
         mask[active] = True
         self.caches, logits = self._run_jit(
             "decode", self._decode,
-            self.caches, jnp.asarray(tables),
+            self.params, self.caches, jnp.asarray(tables),
             self._ring_rows([(i, i) for i in active], self.n_slots),
             tokens, pos, jnp.asarray(mask))
         if self.sample is not None:
@@ -1264,7 +1266,7 @@ class Engine:
             return
         self.draft_caches = self._run_jit(
             "draft_prefill", self._draft_prefill,
-            self.draft_caches, jnp.asarray(tables),
+            self.draft_params, self.draft_caches, jnp.asarray(tables),
             jnp.asarray(rings) if self.ring_len else None,
             jnp.asarray(tokens), jnp.asarray(starts))
         for i, real in live:
@@ -1332,13 +1334,15 @@ class Engine:
                     drings[i] = s.draft_ring_blocks
 
         self.draft_caches, drafts, p_draft = self._run_jit(
-            "draft", self._draft, self.draft_caches, jnp.asarray(dtables),
+            "draft", self._draft, self.draft_params, self.draft_caches,
+            jnp.asarray(dtables),
             jnp.asarray(drings) if self.ring_len else None,
             jnp.asarray(first), jnp.asarray(pos), uids, sidx, temp, topp)
         vtokens = jnp.concatenate([jnp.asarray(first)[:, None], drafts],
                                   axis=1)
         self.caches, logits = self._run_jit(
-            "verify", self._verify, self.caches, jnp.asarray(vtables),
+            "verify", self._verify, self.params, self.caches,
+            jnp.asarray(vtables),
             self._ring_rows([(i, i) for i in active], self.n_slots),
             vtokens, jnp.asarray(pos), jnp.asarray(mask))
         n_acc, toks = self._run_jit(
@@ -1528,7 +1532,7 @@ class Engine:
                     total += s.data.size * s.data.dtype.itemsize
         return total
 
-    def n_compiles(self) -> Optional[int]:
+    def n_compiles(self) -> int:
         """Total jit cache entries across the engine's step functions (the
         no-recompilation-between-steps check in benchmarks/serving.py)."""
         fns = [self._decode, self._prefill_chunk, self._prefill_batched,
@@ -1536,7 +1540,4 @@ class Engine:
         if self.spec:
             fns += [self._draft, self._verify, self._draft_prefill,
                     self._spec_accept]
-        try:
-            return sum(int(f._cache_size()) for f in fns)
-        except AttributeError:                 # older jax: no _cache_size
-            return None
+        return sum(f._cache_size() for f in fns)

@@ -31,7 +31,7 @@ def test_expert_lut_oracle_equals_dequant_formulation():
     lv = quant.uniform_codebook(b, True).levels
     lut = product_lut(lv, lv)
     a_idx, w_idx = _codes(rng, (E, M, K), b), _codes(rng, (E, N, K), b)
-    got = R.ref_expert_lut_gemm(packing.pack(a_idx, b), packing.pack(w_idx, b), lut)
+    got = R.ref_expert_lut_gemm(a_idx, packing.pack(w_idx, b), lut)
     a_deq = jnp.take(lv, a_idx.astype(jnp.int32))
     w_deq = jnp.take(lv, w_idx.astype(jnp.int32))
     want = jnp.einsum("emk,enk->emn", a_deq, w_deq)
@@ -43,13 +43,13 @@ def test_expert_lut_pallas_matches_oracle_grouped_and_not():
     E, M, N, K, b, G = 2, 4, 8, 32, 2, 8
     lv = quant.uniform_codebook(b, True).levels
     lut = product_lut(lv, lv)
-    ap = packing.pack(_codes(rng, (E, M, K), b), b)
+    a_idx = _codes(rng, (E, M, K), b)
     wp = packing.pack(_codes(rng, (E, N, K), b), b)
     sc = jnp.asarray(rng.random((E, N, K // G)), jnp.float32)
     for w_scales, group in ((None, None), (sc, G)):
-        want = R.ref_expert_lut_gemm(ap, wp, lut, w_scales=w_scales,
+        want = R.ref_expert_lut_gemm(a_idx, wp, lut, w_scales=w_scales,
                                      group_size=group)
-        got = kops.dispatch("expert_lut_gemm", ap, wp, lut.table,
+        got = kops.dispatch("expert_lut_gemm", a_idx, wp, lut.table,
                             w_scales, w_bits=lut.w_bits, a_bits=lut.a_bits,
                             group_size=group, backend="pallas_interpret")
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),

@@ -1,5 +1,5 @@
 """Per-kernel correctness: Pallas (interpret=True on CPU) vs pure-jnp ref
-across shapes, bitwidths, packing schemes and lookup implementations."""
+across shapes, bitwidths, K grid steps and lookup implementations."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -7,6 +7,7 @@ import pytest
 
 from repro.core import lut, packing, quant
 from repro.kernels import registry, ref
+from repro.kernels.lut_gemm import LANE, SUBLANE, bf16_split, matmul_blocks
 
 RNG = np.random.default_rng(42)
 
@@ -18,10 +19,19 @@ def _codes(shape, bits, rng=None):
     return jnp.asarray(rng.integers(0, 2 ** bits, size=shape), dtype=jnp.uint8)
 
 
-def _pack_pair(M, N, K, bits, rng=None):
+def _lut_operands(M, N, K, bits, rng=None):
+    """(M, K) activation codes and (N, K/f) packed weight codes."""
     a_idx = _codes((M, K), bits, rng)
     w_idx = _codes((N, K), bits, rng)
-    return packing.pack(a_idx, bits), packing.pack(w_idx, bits)
+    return a_idx, packing.pack(w_idx, bits)
+
+
+def _k_steps(M, N, K, bits, group_size, block, scale_align=SUBLANE):
+    """Number of K grid steps the packed-weight kernels run for ``block``."""
+    bm, bn, bk = block
+    return K // matmul_blocks(M, N, K, bits=bits, group_size=group_size,
+                              bm=bm, bn=bn, bk=bk,
+                              scale_align=scale_align)[2]
 
 
 # --------------------------------------------------------------------------- #
@@ -32,79 +42,95 @@ def _pack_pair(M, N, K, bits, rng=None):
 @pytest.mark.parametrize("shape", [(8, 16, 32), (16, 8, 64), (32, 32, 128)])
 def test_lut_gemm_matches_ref(bits, shape):
     M, N, K = shape
-    ap, wp = _pack_pair(M, N, K, bits)
+    a_idx, wp = _lut_operands(M, N, K, bits)
     cb = quant.uniform_codebook(bits, signed=True)
     plut = lut.product_lut(cb, cb)
-    want = ref.ref_lut_gemm(ap, wp, plut)
-    got = registry.dispatch("lut_gemm", ap, wp, plut.table, None,
+    want = ref.ref_lut_gemm(a_idx, wp, plut)
+    got = registry.dispatch("lut_gemm", a_idx, wp, plut.table, None,
                             w_bits=plut.w_bits, a_bits=plut.a_bits,
                             backend="pallas_interpret",
                             block=(min(8, M), min(16, N), min(64, K)))
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
-@pytest.mark.parametrize("scheme", ["a", "c", "d"])
-def test_lut_gemm_schemes_agree(scheme):
-    M, N, K, bits = 8, 16, 64, 2
-    ap, wp = _pack_pair(M, N, K, bits)
+@pytest.mark.parametrize("bits,K", [(1, 2048), (2, 1024), (4, 512)])
+def test_lut_gemm_k_steps_match_ref(bits, K):
+    """Accumulation over two K grid steps of (8,128)-legal blocks (a K step
+    spans whole lane tiles of packed weight bytes) is exact."""
+    M, N = 8, 16
+    block = (8, 16, K // 2)
+    assert _k_steps(M, N, K, bits, None, block) == 2
+    a_idx, wp = _lut_operands(M, N, K, bits, np.random.default_rng(5))
     cb = quant.uniform_codebook(bits, signed=True)
     plut = lut.product_lut(cb, cb)
-    want = ref.ref_lut_gemm(ap, wp, plut)
-    got = registry.dispatch("lut_gemm", ap, wp, plut.table, None,
-                            w_bits=plut.w_bits, a_bits=plut.a_bits,
-                            scheme=scheme, backend="pallas_interpret",
-                            block=(8, 16, 64))
+    want = ref.ref_lut_gemm(a_idx, wp, plut)
+    got = registry.dispatch("lut_gemm", a_idx, wp, plut.table, None,
+                            w_bits=bits, a_bits=bits,
+                            backend="pallas_interpret", block=block)
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
-
-
-def test_lut_gemm_onehot_lookup_impl():
-    """MXU-routed lookup (one_hot @ lut) must equal the gather lookup."""
-    M, N, K, bits = 8, 16, 64, 2
-    ap, wp = _pack_pair(M, N, K, bits)
-    cb = quant.uniform_codebook(bits, signed=True)
-    plut = lut.product_lut(cb, cb)
-    take = registry.dispatch("lut_gemm", ap, wp, plut.table, None,
-                             w_bits=plut.w_bits, a_bits=plut.a_bits,
-                             lookup_impl="take", backend="pallas_interpret",
-                             block=(8, 16, 64))
-    oneh = registry.dispatch("lut_gemm", ap, wp, plut.table, None,
-                             w_bits=plut.w_bits, a_bits=plut.a_bits,
-                             lookup_impl="onehot", backend="pallas_interpret",
-                             block=(8, 16, 64))
-    np.testing.assert_allclose(np.asarray(take), np.asarray(oneh), atol=1e-4)
 
 
 def test_lut_gemm_nonuniform_float_entries():
     """Paper §5.3: float (non-uniform) LUT entries — signed k-means levels."""
     M, N, K, bits = 8, 8, 32, 2
-    ap, wp = _pack_pair(M, N, K, bits)
+    a_idx, wp = _lut_operands(M, N, K, bits)
     wl = jnp.asarray([-1.3, -0.2, 0.4, 1.7], jnp.float32)
     al = jnp.asarray([-0.9, -0.1, 0.3, 1.1], jnp.float32)
     plut = lut.product_lut(wl, al)
-    want = ref.ref_dequant_gemm(ap, wp, wl, al, bits, bits)
-    got = registry.dispatch("lut_gemm", ap, wp, plut.table, None,
+    want = ref.ref_dequant_gemm(a_idx, wp, wl, al, bits)
+    got = registry.dispatch("lut_gemm", a_idx, wp, plut.table, None,
                             w_bits=plut.w_bits, a_bits=plut.a_bits,
                             backend="pallas_interpret", block=(8, 8, 32))
     np.testing.assert_allclose(np.asarray(want), np.asarray(got),
                                rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("scheme", ["a", "d"])
-@pytest.mark.parametrize("group", [16, 32])
-def test_lut_gemm_grouped_scales_match_ref(scheme, group):
-    """Fused group-scale epilogue vs the grouped oracle, across K tiles."""
-    M, N, K, bits = 8, 16, 128, 2
+def test_bf16_split_is_exact():
+    """Three bf16 parts carry every f32 table entry exactly, so the MXU's
+    bf16 operands lose nothing of a wide (w4a8) table row."""
+    rng = np.random.default_rng(3)
+    x = np.concatenate([rng.integers(-1024, 1025, 512).astype(np.float32),
+                        rng.normal(size=512).astype(np.float32) * 1e3])
+    parts = np.asarray(bf16_split(jnp.asarray(x.reshape(8, 128))),
+                       np.float32)
+    assert parts.shape == (24, 128)
+    np.testing.assert_array_equal(parts[:8] + parts[8:16] + parts[16:],
+                                  x.reshape(8, 128))
+
+
+def test_lut_gemm_w4a8_matches_ref():
+    """Mixed widths (4-bit weights, 8-bit activations): a 4096-entry table
+    whose entries need up to 10 significant bits; the sums stay exact."""
+    M, N, K = 8, 16, 256
+    rng = np.random.default_rng(11)
+    a_idx = _codes((M, K), 8, rng)
+    wp = packing.pack(_codes((N, K), 4, rng), 4)
+    plut = lut.product_lut(quant.uniform_codebook(4, signed=True),
+                           quant.uniform_codebook(8, signed=True))
+    want = ref.ref_lut_gemm(a_idx, wp, plut)
+    got = registry.dispatch("lut_gemm", a_idx, wp, plut.table, None,
+                            w_bits=4, a_bits=8, backend="pallas_interpret")
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+
+@pytest.mark.parametrize("bits,group", [(2, 16), (2, 32), (2, 64), (4, 32)])
+def test_lut_gemm_grouped_scales_match_ref(bits, group):
+    """Fused group-scale epilogue vs the grouped oracle, across two K
+    tiles (the (K/G, N) scale block spans whole sublane tiles per step)."""
+    M, N = 8, 16
+    K = 2 * LANE * packing.PACK_FACTOR[bits]
+    block = (8, 16, K // 2)
+    assert _k_steps(M, N, K, bits, group, block) == 2
     rng = np.random.default_rng(7)
-    ap, wp = _pack_pair(M, N, K, bits, rng)
+    a_idx, wp = _lut_operands(M, N, K, bits, rng)
     cb = quant.uniform_codebook(bits, signed=True)
     plut = lut.product_lut(cb, cb)
     sc = jnp.asarray(np.abs(rng.normal(size=(N, K // group))) + 0.05,
                      jnp.float32)
-    want = ref.ref_lut_gemm(ap, wp, plut, w_scales=sc, group_size=group)
-    got = registry.dispatch("lut_gemm", ap, wp, plut.table, sc,
-                            w_bits=plut.w_bits, a_bits=plut.a_bits,
-                            scheme=scheme, group_size=group,
-                            backend="pallas_interpret", block=(8, 16, 64))
+    want = ref.ref_lut_gemm(a_idx, wp, plut, w_scales=sc, group_size=group)
+    got = registry.dispatch("lut_gemm", a_idx, wp, plut.table, sc,
+                            w_bits=bits, a_bits=bits, group_size=group,
+                            backend="pallas_interpret", block=block)
     np.testing.assert_allclose(np.asarray(want), np.asarray(got),
                                rtol=1e-5, atol=1e-5)
 
@@ -114,15 +140,15 @@ def test_lut_gemm_grouped_equals_scaled_dequant():
     (the plan's accuracy lever is a pure reparametrization)."""
     M, N, K, bits, G = 4, 8, 64, 2, 16
     rng = np.random.default_rng(8)
-    ap, wp = _pack_pair(M, N, K, bits, rng)
+    a_idx, wp = _lut_operands(M, N, K, bits, rng)
     cb = quant.uniform_codebook(bits, signed=True)
     sc = jnp.asarray(np.abs(rng.normal(size=(N, K // G))) + 0.05, jnp.float32)
     plut = lut.product_lut(cb, cb)
-    got = registry.dispatch("lut_gemm", ap, wp, plut.table, sc,
+    got = registry.dispatch("lut_gemm", a_idx, wp, plut.table, sc,
                             w_bits=plut.w_bits, a_bits=plut.a_bits,
                             group_size=G, backend="pallas_interpret",
                             block=(4, 8, 64))
-    a_deq = jnp.take(cb.levels, packing.unpack(ap, bits).astype(jnp.int32))
+    a_deq = jnp.take(cb.levels, a_idx.astype(jnp.int32))
     w_deq = jnp.take(cb.levels, packing.unpack(wp, bits).astype(jnp.int32))
     w_deq = w_deq * jnp.repeat(sc, G, axis=-1)
     want = a_deq @ w_deq.T
@@ -132,36 +158,31 @@ def test_lut_gemm_grouped_equals_scaled_dequant():
 
 @pytest.mark.parametrize("wb,ab", [(4, 8), (2, 8), (2, 4), (8, 4)])
 def test_lut_gemm_asymmetric_bits_match_ref(wb, ab):
-    """Mixed operand widths (ROADMAP carried bug): the kernel used one pack
-    factor for both operands, so w4a8 (2 weight codes/byte vs 1 activation
-    code/byte) tripped the packed-width assert. K must come from each
-    operand's own factor and the index shift from a_bits."""
+    """Mixed operand widths: the table index shifts the weight code by
+    a_bits, and K comes from the weight's own pack factor."""
     M, N, K = 8, 16, 64
     rng = np.random.default_rng(11)
-    ap = packing.pack(_codes((M, K), ab, rng), ab)
+    a_idx = _codes((M, K), ab, rng)
     wp = packing.pack(_codes((N, K), wb, rng), wb)
-    assert ap.shape[-1] != wp.shape[-1]      # the regression's trigger
     plut = lut.product_lut(quant.uniform_codebook(wb, signed=True),
                            quant.uniform_codebook(ab, signed=True))
-    want = ref.ref_lut_gemm(ap, wp, plut)
-    for scheme in ("a", "d"):
-        got = registry.dispatch("lut_gemm", ap, wp, plut.table, None,
-                                w_bits=wb, a_bits=ab, scheme=scheme,
-                                backend="pallas_interpret",
-                                block=(8, 16, 32))
-        np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+    want = ref.ref_lut_gemm(a_idx, wp, plut)
+    got = registry.dispatch("lut_gemm", a_idx, wp, plut.table, None,
+                            w_bits=wb, a_bits=ab,
+                            backend="pallas_interpret", block=(8, 16, 32))
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
 
 def test_lut_gemm_asymmetric_grouped_scales():
     M, N, K, wb, ab, G = 8, 8, 128, 4, 8, 32
     rng = np.random.default_rng(12)
-    ap = packing.pack(_codes((M, K), ab, rng), ab)
+    a_idx = _codes((M, K), ab, rng)
     wp = packing.pack(_codes((N, K), wb, rng), wb)
     plut = lut.product_lut(quant.uniform_codebook(wb, signed=True),
                            quant.uniform_codebook(ab, signed=True))
     sc = jnp.asarray(np.abs(rng.normal(size=(N, K // G))) + 0.05, jnp.float32)
-    want = ref.ref_lut_gemm(ap, wp, plut, w_scales=sc, group_size=G)
-    got = registry.dispatch("lut_gemm", ap, wp, plut.table, sc,
+    want = ref.ref_lut_gemm(a_idx, wp, plut, w_scales=sc, group_size=G)
+    got = registry.dispatch("lut_gemm", a_idx, wp, plut.table, sc,
                             w_bits=wb, a_bits=ab, group_size=G,
                             backend="pallas_interpret", block=(8, 8, 64))
     np.testing.assert_allclose(np.asarray(want), np.asarray(got),
@@ -170,22 +191,23 @@ def test_lut_gemm_asymmetric_grouped_scales():
 
 def test_lut65k_matches_lut16():
     M, N, K, bits = 4, 8, 32, 2
-    ap, wp = _pack_pair(M, N, K, bits)
+    a_idx, wp = _lut_operands(M, N, K, bits)
     cb = quant.uniform_codebook(bits, signed=True)
     plut = lut.product_lut(cb, cb)
-    want = ref.ref_lut_gemm(ap, wp, plut)
+    want = ref.ref_lut_gemm(a_idx, wp, plut)
     t65 = lut.lut65k(cb, cb)
-    got = registry.dispatch("lut65k_gemm", ap, wp, t65, backend="ref")
+    got = registry.dispatch("lut65k_gemm", packing.pack(a_idx, bits), wp,
+                            t65, backend="ref")
     np.testing.assert_allclose(np.asarray(want), np.asarray(got), atol=1e-4)
 
 
 def test_fused_scale_lut():
     """Scales folded into the table == scaling outside (paper's op fusion)."""
     M, N, K, bits = 4, 8, 32, 2
-    ap, wp = _pack_pair(M, N, K, bits)
+    a_idx, wp = _lut_operands(M, N, K, bits)
     cb = quant.uniform_codebook(bits, signed=True)
-    plain = ref.ref_lut_gemm(ap, wp, lut.product_lut(cb, cb))
-    fused = ref.ref_lut_gemm(ap, wp, lut.fused_lut(cb, cb, 0.25, 0.5))
+    plain = ref.ref_lut_gemm(a_idx, wp, lut.product_lut(cb, cb))
+    fused = ref.ref_lut_gemm(a_idx, wp, lut.fused_lut(cb, cb, 0.25, 0.5))
     np.testing.assert_allclose(np.asarray(plain) * 0.125, np.asarray(fused),
                                rtol=1e-6)
 
@@ -249,17 +271,28 @@ def test_dequant_matmul_nondivisible_blocks_fit():
     np.testing.assert_allclose(np.asarray(want), np.asarray(got), rtol=1e-4)
 
 
-def test_dequant_matmul_grid_accumulation():
-    """K-grid accumulation across multiple k steps must be exact."""
-    M, N, K, bits = 16, 16, 512, 2
-    a = jnp.asarray(RNG.normal(size=(M, K)), jnp.float32)
-    wp = packing.pack(_codes((N, K), bits), bits)
+@pytest.mark.parametrize("group", [None, 16])
+def test_dequant_matmul_grid_accumulation(group):
+    """K-grid accumulation across two k steps of (8,128)-legal blocks: a
+    step spans whole lane tiles of packed bytes and, grouped, of the
+    (N, K/G) scale block."""
+    M, N, bits = 16, 16, 2
+    K = 1024 if group is None else 2 * LANE * group
+    block = (8, 8, K // 2)
+    assert _k_steps(M, N, K, bits, group, block, scale_align=LANE) == 2
+    rng = np.random.default_rng(13)
+    a = jnp.asarray(rng.normal(size=(M, K)), jnp.float32)
+    wp = packing.pack(_codes((N, K), bits, rng), bits)
     cb = quant.uniform_codebook(bits, signed=True)
-    sc = jnp.ones((N,), jnp.float32)
-    want = ref.ref_dequant_matmul(a, wp, cb.levels, sc, bits)
+    sc = jnp.ones((N,), jnp.float32) if group is None else jnp.asarray(
+        np.abs(rng.normal(size=(N, K // group))) + 0.05, jnp.float32)
+    want = ref.ref_dequant_matmul(a, wp, cb.levels, sc, bits,
+                                  group_size=group)
     got = registry.dispatch("dequant_matmul", a, wp, cb.levels, sc, bits=bits,
-                             backend="pallas_interpret", block=(8, 8, 128))
-    np.testing.assert_allclose(np.asarray(want), np.asarray(got), rtol=1e-4)
+                             group_size=group, backend="pallas_interpret",
+                             block=block)
+    np.testing.assert_allclose(np.asarray(want), np.asarray(got),
+                               rtol=1e-4, atol=1e-4)
 
 
 # --------------------------------------------------------------------------- #

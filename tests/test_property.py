@@ -108,11 +108,11 @@ def test_lut_gemm_equals_dequant_gemm_exactly(bits, m, n, kg, seed, signed):
     f = packing.PACK_FACTOR[bits]
     K = kg * f
     rng = np.random.default_rng(seed)
-    ap = packing.pack(jnp.asarray(rng.integers(0, 2 ** bits, (m, K)), jnp.uint8), bits)
+    a_idx = jnp.asarray(rng.integers(0, 2 ** bits, (m, K)), jnp.uint8)
     wp = packing.pack(jnp.asarray(rng.integers(0, 2 ** bits, (n, K)), jnp.uint8), bits)
     cb = quant.uniform_codebook(bits, signed)
-    got = ref.ref_lut_gemm(ap, wp, lut.product_lut(cb, cb))
-    want = ref.ref_dequant_gemm(ap, wp, cb.levels, cb.levels, bits, bits)
+    got = ref.ref_lut_gemm(a_idx, wp, lut.product_lut(cb, cb))
+    want = ref.ref_dequant_gemm(a_idx, wp, cb.levels, cb.levels, bits)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 

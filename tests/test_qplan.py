@@ -190,24 +190,22 @@ def test_k_padding_to_group_multiple():
 
 
 # --------------------------------------------------------------------------- #
-# Scheme reconciliation (ISSUE satellite: quantize_weight vs lut_gemm 'd')
+# quantize_weight packs what lut_gemm unpacks
 # --------------------------------------------------------------------------- #
 
-@pytest.mark.parametrize("scheme", ["a", "c", "d"])
-def test_quantize_weight_scheme_dispatch_matches_ref(scheme):
-    """What quantize_weight packs is what lut_gemm unpacks, for every
-    scheme: the leaf records its scheme and dense_serve dispatches with it
-    explicitly. Schemes 'c'/'d' are byte-identical to 'a' (the index-ready
-    trick is in the unpack masks), so the natural-unpack oracle is valid."""
+@pytest.mark.parametrize("w_bits,a_bits", [(2, 2), (2, 4), (4, 8)])
+def test_quantize_weight_lut_dispatch_matches_ref(w_bits, a_bits):
+    """What quantize_weight packs (the natural slot layout) is what
+    lut_gemm unpacks: dense_serve through the Pallas LUT route equals its
+    shardable dequant formulation on the 'ref' backend."""
     w = jax.random.normal(KEY, (32, 16))
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 32))
-    pol = QuantPolicy(w_bits=2, a_bits=2, scheme=scheme, kernel="auto")
+    pol = QuantPolicy(w_bits=w_bits, a_bits=a_bits, kernel="auto")
     qw = quantize_weight(w, pol)
-    assert qw.scheme == scheme
-    # byte-identity of the packing across schemes
-    idx = packing.unpack(qw.packed, 2)
+    assert qw.scheme == "a" and qw.kernel == "lut_gemm"
+    idx = packing.unpack(qw.packed, w_bits)
     np.testing.assert_array_equal(
-        np.asarray(packing.pack(idx, 2)), np.asarray(qw.packed))
+        np.asarray(packing.pack(idx, w_bits)), np.asarray(qw.packed))
     y_ref = dense_serve(qw, x, backend="ref")
     y_pal = dense_serve(qw, x, backend="pallas_interpret")
     np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_pal),

@@ -18,8 +18,7 @@ def _lut_case(M=4, N=8, K=32, bits=2):
     a_idx = jnp.asarray(RNG.integers(0, 2 ** bits, (M, K)), jnp.uint8)
     w_idx = jnp.asarray(RNG.integers(0, 2 ** bits, (N, K)), jnp.uint8)
     cb = quant.uniform_codebook(bits, signed=True)
-    return (packing.pack(a_idx, bits), packing.pack(w_idx, bits),
-            lut.product_lut(cb, cb))
+    return a_idx, packing.pack(w_idx, bits), lut.product_lut(cb, cb)
 
 
 def test_registry_lists_all_ops():
@@ -41,9 +40,9 @@ def test_unknown_op_raises_with_listing():
 
 
 def test_dispatch_counts_name_and_backend():
-    ap, wp, plut = _lut_case()
+    a_idx, wp, plut = _lut_case()
     with obs_metrics.scoped() as reg:
-        registry.dispatch("lut_gemm", ap, wp, plut.table, None,
+        registry.dispatch("lut_gemm", a_idx, wp, plut.table, None,
                           w_bits=plut.w_bits, a_bits=plut.a_bits,
                           backend="ref")
     c = reg.dispatch_counts()
@@ -53,9 +52,9 @@ def test_dispatch_counts_name_and_backend():
 def test_dispatch_counter_labels():
     """The registry records per-(op, backend, m-bucket, bits) labels on the
     unified kernel_dispatch_total counter (docs/observability.md)."""
-    ap, wp, plut = _lut_case(M=4)
+    a_idx, wp, plut = _lut_case(M=4)
     with obs_metrics.scoped() as reg:
-        registry.dispatch("lut_gemm", ap, wp, plut.table, None,
+        registry.dispatch("lut_gemm", a_idx, wp, plut.table, None,
                           w_bits=plut.w_bits, a_bits=plut.a_bits,
                           backend="ref")
     n = reg.get(obs_metrics.KERNEL_DISPATCH, op="lut_gemm", backend="ref",
@@ -64,21 +63,21 @@ def test_dispatch_counter_labels():
 
 
 def test_ref_and_pallas_backends_agree():
-    ap, wp, plut = _lut_case()
-    r = registry.dispatch("lut_gemm", ap, wp, plut.table, None,
+    a_idx, wp, plut = _lut_case()
+    r = registry.dispatch("lut_gemm", a_idx, wp, plut.table, None,
                           w_bits=plut.w_bits, a_bits=plut.a_bits,
                           backend="ref")
-    p = registry.dispatch("lut_gemm", ap, wp, plut.table, None,
+    p = registry.dispatch("lut_gemm", a_idx, wp, plut.table, None,
                           w_bits=plut.w_bits, a_bits=plut.a_bits,
                           backend="pallas_interpret")
     np.testing.assert_array_equal(np.asarray(r), np.asarray(p))
 
 
 def test_block_override_changes_grid_not_result():
-    ap, wp, plut = _lut_case(M=8, N=16, K=128)
-    want = ref.ref_lut_gemm(ap, wp, plut)
+    a_idx, wp, plut = _lut_case(M=8, N=16, K=128)
+    want = ref.ref_lut_gemm(a_idx, wp, plut)
     for block in [(8, 16, 64), (4, 8, 32), (2, 16, 128)]:
-        got = registry.dispatch("lut_gemm", ap, wp, plut.table, None,
+        got = registry.dispatch("lut_gemm", a_idx, wp, plut.table, None,
                                 w_bits=plut.w_bits, a_bits=plut.a_bits,
                                 backend="pallas_interpret", block=block)
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
@@ -87,12 +86,12 @@ def test_block_override_changes_grid_not_result():
 def test_none_operand_slots_are_reinserted():
     """Optional operands (group scales) pass positionally as None and the
     impl still sees its full arity — grouped vs ungrouped both dispatch."""
-    ap, wp, plut = _lut_case(M=4, N=8, K=32)
+    a_idx, wp, plut = _lut_case(M=4, N=8, K=32)
     sc = jnp.asarray(RNG.random((8, 32 // 8)) + 0.05, jnp.float32)
-    got = registry.dispatch("lut_gemm", ap, wp, plut.table, sc,
+    got = registry.dispatch("lut_gemm", a_idx, wp, plut.table, sc,
                             w_bits=plut.w_bits, a_bits=plut.a_bits,
                             group_size=8, backend="pallas_interpret")
-    want = ref.ref_lut_gemm(ap, wp, plut, w_scales=sc, group_size=8)
+    want = ref.ref_lut_gemm(a_idx, wp, plut, w_scales=sc, group_size=8)
     np.testing.assert_allclose(np.asarray(want), np.asarray(got), atol=1e-5)
 
 
@@ -140,14 +139,14 @@ def test_registry_counter_shims_removed():
     for name in ("DISPATCH_COUNTS", "dispatch_counts",
                  "reset_dispatch_counts"):
         assert not hasattr(registry, name), name
-    ap, wp, plut = _lut_case()
+    a_idx, wp, plut = _lut_case()
     with obs_metrics.scoped() as reg:
-        registry.dispatch("lut_gemm", ap, wp, plut.table, None,
+        registry.dispatch("lut_gemm", a_idx, wp, plut.table, None,
                           w_bits=plut.w_bits, a_bits=plut.a_bits,
                           backend="ref")
         # isolated scopes (the autotuner's probe mode) stay invisible
         with obs_metrics.scoped(isolate=True):
-            registry.dispatch("lut_gemm", ap, wp, plut.table, None,
+            registry.dispatch("lut_gemm", a_idx, wp, plut.table, None,
                               w_bits=plut.w_bits, a_bits=plut.a_bits,
                               backend="ref")
     c = reg.dispatch_counts()

@@ -61,7 +61,7 @@ def test_parser_counts_scan_trip_counts():
     hlo_s = jax.jit(f_scan).lower(x, ws).compile().as_text()
     c_u = jax.jit(f_unroll).lower(x, ws).compile()
     stats = RL.parse_hlo(hlo_s)
-    want = RL.xla_cost(c_u)["flops"]
+    want = c_u.cost_analysis()["flops"]
     assert stats.unknown_trip_counts == 0
     np.testing.assert_allclose(stats.dot_flops, want, rtol=0.02)
 
@@ -81,7 +81,7 @@ def test_parser_vs_cost_analysis_on_unrolled_model():
 
     compiled = jax.jit(fwd).lower(params, tokens).compile()
     stats = RL.parse_hlo(compiled.as_text())
-    xla = RL.xla_cost(compiled)["flops"]
+    xla = compiled.cost_analysis()["flops"]
     # single superblock: the layer scan has trip 1; chunk scans also 1
     assert stats.dot_flops <= xla * 1.05
     assert stats.dot_flops >= 0.5 * xla, (stats.dot_flops, xla)

@@ -136,32 +136,32 @@ def test_tp_sharded_kernels_match_unsharded():
         lut = product_lut(lv, lv)
         a_idx = jnp.asarray(rng.integers(0, 4, (M, K)), jnp.uint8)
         w_idx = jnp.asarray(rng.integers(0, 4, (N, K)), jnp.uint8)
-        ap, wp = packing.pack(a_idx, b), packing.pack(w_idx, b)
+        wp = packing.pack(w_idx, b)
         sc = jnp.asarray(rng.random((N, K // G)), jnp.float32)
         ea = jnp.asarray(rng.integers(0, 4, (E, M, K)), jnp.uint8)
         ew = jnp.asarray(rng.integers(0, 4, (E, N, K)), jnp.uint8)
-        eap, ewp = packing.pack(ea, b), packing.pack(ew, b)
-        base = kops.dispatch("lut_gemm", ap, wp, lut.table, sc,
+        ewp = packing.pack(ew, b)
+        base = kops.dispatch("lut_gemm", a_idx, wp, lut.table, sc,
                              w_bits=b, a_bits=b, group_size=G,
                              backend="pallas_interpret")
-        ebase = kops.dispatch("expert_lut_gemm", eap, ewp, lut.table, None,
+        ebase = kops.dispatch("expert_lut_gemm", ea, ewp, lut.table, None,
                               w_bits=b, a_bits=b,
                               backend="pallas_interpret")
         for role, tol in (("col", 0.0), ("row", 1e-4)):
-            def f(ap, wp, sc):
+            def f(a_idx, wp, sc):
                 with Sh.use_tp(mesh):
-                    return kops.dispatch("lut_gemm", ap, wp, lut.table, sc,
+                    return kops.dispatch("lut_gemm", a_idx, wp, lut.table, sc,
                                          w_bits=b, a_bits=b, group_size=G,
                                          backend="pallas_interpret", tp=role)
-            got = jax.jit(f)(ap, wp, sc)
+            got = jax.jit(f)(a_idx, wp, sc)
             np.testing.assert_allclose(np.asarray(got), np.asarray(base),
                                        atol=max(tol, 1e-12))
-            def g(eap, ewp):
+            def g(ea, ewp):
                 with Sh.use_tp(mesh):
-                    return kops.dispatch("expert_lut_gemm", eap, ewp,
+                    return kops.dispatch("expert_lut_gemm", ea, ewp,
                                          lut.table, None, w_bits=b, a_bits=b,
                                          backend="pallas_interpret", tp=role)
-            egot = jax.jit(g)(eap, ewp)
+            egot = jax.jit(g)(ea, ewp)
             np.testing.assert_allclose(np.asarray(egot), np.asarray(ebase),
                                        atol=max(tol, 1e-12))
         print("sharded kernels OK")
@@ -185,15 +185,15 @@ def test_tp_nondividing_shapes_fall_back():
         lut = product_lut(lv, lv)
         a_idx = jnp.asarray(rng.integers(0, 4, (4, 12)), jnp.uint8)
         w_idx = jnp.asarray(rng.integers(0, 4, (6, 12)), jnp.uint8)   # N=6 !% 8
-        ap, wp = packing.pack(a_idx, b), packing.pack(w_idx, b)
-        base = kops.dispatch("lut_gemm", ap, wp, lut.table, None,
+        wp = packing.pack(w_idx, b)
+        base = kops.dispatch("lut_gemm", a_idx, wp, lut.table, None,
                              w_bits=b, a_bits=b, backend="pallas_interpret")
-        def f(ap, wp):
+        def f(a_idx, wp):
             with Sh.use_tp(mesh):
-                return kops.dispatch("lut_gemm", ap, wp, lut.table, None,
+                return kops.dispatch("lut_gemm", a_idx, wp, lut.table, None,
                                      w_bits=b, a_bits=b,
                                      backend="pallas_interpret", tp="col")
-        np.testing.assert_array_equal(np.asarray(jax.jit(f)(ap, wp)),
+        np.testing.assert_array_equal(np.asarray(jax.jit(f)(a_idx, wp)),
                                       np.asarray(base))
         # col role refused when out % tp != 0; row pads K to the shard split
         w = jnp.asarray(rng.standard_normal((16, 6)), jnp.float32)
