@@ -39,6 +39,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation
 
 from repro.configs import get_config, reduce_for_smoke
 from repro.core.qlinear import QuantPolicy
@@ -480,7 +481,8 @@ def main():
     if cfg.mrope_sections:
         pf_batch["positions"] = frontends.mrope_positions(
             B, P, cfg.n_vision_tokens)
-    logits, caches = prefill(qparams, pf_batch)
+    with StepTraceAnnotation("prefill", step_num=0):
+        logits, caches = prefill(qparams, pf_batch)
     caches = jax.block_until_ready(caches)
     t_prefill = time.time() - t0
     print(f"  prefill {B}x{P}: {t_prefill*1e3:.1f} ms")
@@ -493,7 +495,8 @@ def main():
         if cfg.mrope_sections:
             batch["positions"] = jnp.broadcast_to(
                 (P + i) + jnp.zeros((B, 1, 3), jnp.int32), (B, 1, 3))
-        logits, caches = decode(qparams, caches, batch)
+        with StepTraceAnnotation("decode", step_num=i + 1):
+            logits, caches = decode(qparams, caches, batch)
         out_tokens.append(jnp.argmax(logits[:, -1], -1))
     jax.block_until_ready(out_tokens[-1])
     t_dec = time.time() - t0

@@ -18,7 +18,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import optim
+from repro import obs, optim
 from repro.dist import sharding
 from repro.models import lm
 
@@ -157,7 +157,8 @@ def make_prefill_step(cfg, *, mode: str = "plain", max_len: Optional[int] = None
         S = batch["tokens"].shape[1]
         h, caches = lm.forward(params, cfg, batch["tokens"], mode=mode,
                                collect_cache=True, **_fwd_kwargs(batch))
-        logits = lm.logits_fn(params, cfg, h[:, -1:])
+        with obs.scope("lm_head"):
+            logits = lm.logits_fn(params, cfg, h[:, -1:])
         dec = lm.prefill_to_cache(cfg, caches, S, max_len or S)
         return logits, dec
 

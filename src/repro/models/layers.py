@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from repro import obs
 from repro.core import calibrate, quant
 from repro.core.qlinear import (QuantPolicy, QuantizedWeight, dense_serve,
                                 dequant_weight)
@@ -99,15 +100,16 @@ def norm_init(d: int, kind: str, dtype=jnp.float32) -> dict:
 
 
 def norm_apply(p: dict, x: jax.Array, kind: str, eps: float = 1e-6) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    if kind == "layernorm":
-        mu = xf.mean(-1, keepdims=True)
-        xf = xf - mu
-    var = (xf * xf).mean(-1, keepdims=True)
-    y = xf * jax.lax.rsqrt(var + eps) * p["scale"].astype(jnp.float32)
-    if "bias" in p:
-        y = y + p["bias"].astype(jnp.float32)
-    return y.astype(x.dtype)
+    with obs.scope("norm"):
+        xf = x.astype(jnp.float32)
+        if kind == "layernorm":
+            mu = xf.mean(-1, keepdims=True)
+            xf = xf - mu
+        var = (xf * xf).mean(-1, keepdims=True)
+        y = xf * jax.lax.rsqrt(var + eps) * p["scale"].astype(jnp.float32)
+        if "bias" in p:
+            y = y + p["bias"].astype(jnp.float32)
+        return y.astype(x.dtype)
 
 
 # --------------------------------------------------------------------------- #
@@ -395,33 +397,41 @@ def attn_apply(
     cross = enc_out is not None or (cache is not None and "xk" in cache)
     window = cfg.window if layer_type == "local" else None
 
-    q = dense(p["wq"], x, tag="attn.wq", policy=pol, mode=mode)
-    q = q.reshape(B, S, KV, G, hd)
-    q = shard(q, "batch", "seq", "kv_heads_act", None, None)
+    with obs.scope("attn/qkv"):
+        q = dense(p["wq"], x, tag="attn.wq", policy=pol, mode=mode)
+        q = q.reshape(B, S, KV, G, hd)
+        q = shard(q, "batch", "seq", "kv_heads_act", None, None)
 
     new_cache = None
     if cross:
         if cache is not None and "xk" in cache:
             k, v = cache["xk"], cache["xv"]
         else:
-            k = dense(p["wk"], enc_out, tag="attn.wk", policy=pol, mode=mode)
-            v = dense(p["wv"], enc_out, tag="attn.wv", policy=pol, mode=mode)
-            k = k.reshape(B, -1, KV, hd)
-            v = v.reshape(B, -1, KV, hd)
+            with obs.scope("attn/qkv"):
+                k = dense(p["wk"], enc_out, tag="attn.wk", policy=pol,
+                          mode=mode)
+                v = dense(p["wv"], enc_out, tag="attn.wv", policy=pol,
+                          mode=mode)
+                k = k.reshape(B, -1, KV, hd)
+                v = v.reshape(B, -1, KV, hd)
             new_cache = {"xk": k, "xv": v}
-        out = flash_attention(q, k, v, causal=False)
+        with obs.scope("attn/core"):
+            out = flash_attention(q, k, v, causal=False)
     else:
-        k = dense(p["wk"], x, tag="attn.wk", policy=pol, mode=mode).reshape(B, S, KV, hd)
-        v = dense(p["wv"], x, tag="attn.wv", policy=pol, mode=mode).reshape(B, S, KV, hd)
-        if cfg.pos_embed == "rope":
-            if positions is None:
-                # (1, S) when batch-independent: keeps cos/sin tables tiny
-                # instead of materializing (B, S, hd) angle tensors.
-                positions = (jnp.arange(S)[None, :] if pos is None
-                             else pos[:, None] + jnp.arange(S)[None, :])
-            q = apply_rope(q.reshape(B, S, H, hd), positions, cfg.rope_theta,
-                           cfg.mrope_sections).reshape(B, S, KV, G, hd)
-            k = apply_rope(k, positions, cfg.rope_theta, cfg.mrope_sections)
+        with obs.scope("attn/qkv"):
+            k = dense(p["wk"], x, tag="attn.wk", policy=pol, mode=mode).reshape(B, S, KV, hd)
+            v = dense(p["wv"], x, tag="attn.wv", policy=pol, mode=mode).reshape(B, S, KV, hd)
+            if cfg.pos_embed == "rope":
+                if positions is None:
+                    # (1, S) when batch-independent: keeps cos/sin tables
+                    # tiny instead of materializing (B, S, hd) angle tensors.
+                    positions = (jnp.arange(S)[None, :] if pos is None
+                                 else pos[:, None] + jnp.arange(S)[None, :])
+                q = apply_rope(q.reshape(B, S, H, hd), positions,
+                               cfg.rope_theta,
+                               cfg.mrope_sections).reshape(B, S, KV, G, hd)
+                k = apply_rope(k, positions, cfg.rope_theta,
+                               cfg.mrope_sections)
         if cache is not None and (block_tables is not None
                                   or ring_tables is not None):
             # Paged cache (serving engine): the layer cache is a shared block
@@ -435,8 +445,9 @@ def attn_apply(
             int8_cache = cfg.kv_cache_dtype in KV_QUANT and "k_sc" in cache
             if int8_cache:
                 qf, dqf = KV_QUANT[cfg.kv_cache_dtype]
-                k, k_sc = qf(k)
-                v, v_sc = qf(v)
+                with obs.scope("attn/kv_write"):
+                    k, k_sc = qf(k)
+                    v, v_sc = qf(v)
 
             if layer_type == "local" and ring_tables is not None:
                 # Ring-paged local layer: the pool holds only ring_len blocks
@@ -457,60 +468,59 @@ def attn_apply(
                     g = pool[ring_tables]                # (B, ring_len, bs,.)
                     return g.reshape(B, R, *pool.shape[2:])
 
-                if int8_cache:
-                    kd = jnp.concatenate(
-                        [dqf(rgather(cache["k"]), rgather(cache["k_sc"])),
-                         dqf(k, k_sc)], axis=1)
-                    vd = jnp.concatenate(
-                        [dqf(rgather(cache["v"]), rgather(cache["v_sc"])),
-                         dqf(v, v_sc)], axis=1)
-                else:
-                    kd = jnp.concatenate(
-                        [rgather(cache["k"]), k.astype(cache["k"].dtype)],
-                        axis=1)
-                    vd = jnp.concatenate(
-                        [rgather(cache["v"]), v.astype(cache["v"].dtype)],
-                        axis=1)
+                with obs.scope("attn/core"):
+                    if int8_cache:
+                        kd = jnp.concatenate(
+                            [dqf(rgather(cache["k"]), rgather(cache["k_sc"])),
+                             dqf(k, k_sc)], axis=1)
+                        vd = jnp.concatenate(
+                            [dqf(rgather(cache["v"]), rgather(cache["v_sc"])),
+                             dqf(v, v_sc)], axis=1)
+                    else:
+                        kd = jnp.concatenate(
+                            [rgather(cache["k"]), k.astype(cache["k"].dtype)],
+                            axis=1)
+                        vd = jnp.concatenate(
+                            [rgather(cache["v"]), v.astype(cache["v"].dtype)],
+                            axis=1)
 
-                last = pos - 1                           # newest ring row
-                ridx = jnp.arange(R)[None, :]
-                qabs = last[:, None] - jnp.mod(last[:, None] - ridx, R)
-                t = pos[:, None] + jnp.arange(S)[None, :]          # (B, S)
-                valid_ring = ((qabs[:, None, :] >= 0)
-                              & (qabs[:, None, :] > t[:, :, None] - window))
-                sidx = jnp.arange(S)
-                valid_cur = ((sidx[None, None, :] <= sidx[None, :, None])
-                             & (sidx[None, :, None] - sidx[None, None, :]
-                                < window))
-                valid = jnp.concatenate(
-                    [valid_ring, jnp.broadcast_to(valid_cur, (B, S, S))],
-                    axis=2)
-                out = masked_attention(q, kd, vd, valid)
+                    last = pos - 1                       # newest ring row
+                    ridx = jnp.arange(R)[None, :]
+                    qabs = last[:, None] - jnp.mod(last[:, None] - ridx, R)
+                    t = pos[:, None] + jnp.arange(S)[None, :]      # (B, S)
+                    valid_ring = ((qabs[:, None, :] >= 0)
+                                  & (qabs[:, None, :] > t[:, :, None] - window))
+                    sidx = jnp.arange(S)
+                    valid_cur = ((sidx[None, None, :] <= sidx[None, :, None])
+                                 & (sidx[None, :, None] - sidx[None, None, :]
+                                    < window))
+                    valid = jnp.concatenate(
+                        [valid_ring, jnp.broadcast_to(valid_cur, (B, S, S))],
+                        axis=2)
+                    out = masked_attention(q, kd, vd, valid)
 
-                rows = pos[:, None] + jnp.arange(S)[None, :]
-                blk = jnp.take_along_axis(
-                    ring_tables, (rows // bs_tok) % ring_len, axis=1)
-                offs = rows % bs_tok
-                new_cache = {"k": _scatter_pool_rows(cache["k"], k, blk, offs),
-                             "v": _scatter_pool_rows(cache["v"], v, blk, offs)}
-                if int8_cache:
-                    new_cache["k_sc"] = _scatter_pool_rows(cache["k_sc"],
-                                                           k_sc, blk, offs)
-                    new_cache["v_sc"] = _scatter_pool_rows(cache["v_sc"],
-                                                           v_sc, blk, offs)
-                out = out.reshape(B, S, H * hd)
-                out = shard(out, "batch", "seq", "heads_act")
-                y = dense(p["wo"], out, tag="attn.wo", policy=pol, mode=mode)
-                y = checkpoint_name(
-                    shard(y, "batch", "seq_sp", "embed_act"), "block_out")
-                return y, new_cache  # ring epilogue mirrors the shared tail
+                with obs.scope("attn/kv_write"):
+                    rows = pos[:, None] + jnp.arange(S)[None, :]
+                    blk = jnp.take_along_axis(
+                        ring_tables, (rows // bs_tok) % ring_len, axis=1)
+                    offs = rows % bs_tok
+                    new_cache = {
+                        "k": _scatter_pool_rows(cache["k"], k, blk, offs),
+                        "v": _scatter_pool_rows(cache["v"], v, blk, offs)}
+                    if int8_cache:
+                        new_cache["k_sc"] = _scatter_pool_rows(
+                            cache["k_sc"], k_sc, blk, offs)
+                        new_cache["v_sc"] = _scatter_pool_rows(
+                            cache["v_sc"], v_sc, blk, offs)
+                return _attn_out(p, out, cfg=cfg, mode=mode), new_cache
 
             nb = block_tables.shape[1]
             S_view = nb * bs_tok
-            rows = pos[:, None] + jnp.arange(S)[None, :]             # (B, S)
-            blk = jnp.take_along_axis(
-                block_tables, jnp.minimum(rows // bs_tok, nb - 1), axis=1)
-            offs = rows % bs_tok
+            with obs.scope("attn/kv_write"):
+                rows = pos[:, None] + jnp.arange(S)[None, :]         # (B, S)
+                blk = jnp.take_along_axis(
+                    block_tables, jnp.minimum(rows // bs_tok, nb - 1), axis=1)
+                offs = rows % bs_tok
 
             if kv_splits is not None and int(kv_splits) > 1 and S == 1:
                 # Flash-decoding split-KV decode: scatter the new row FIRST,
@@ -523,146 +533,168 @@ def attn_apply(
                 # score/value contractions accumulate straight off the pool
                 # dtype — which is what makes long-context decode faster
                 # than single-pass.
-                new_cache = {"k": _scatter_pool_rows(cache["k"], k, blk, offs),
-                             "v": _scatter_pool_rows(cache["v"], v, blk, offs)}
-                if int8_cache:
-                    new_cache["k_sc"] = _scatter_pool_rows(cache["k_sc"],
-                                                           k_sc, blk, offs)
-                    new_cache["v_sc"] = _scatter_pool_rows(cache["v_sc"],
-                                                           v_sc, blk, offs)
+                with obs.scope("attn/kv_write"):
+                    new_cache = {
+                        "k": _scatter_pool_rows(cache["k"], k, blk, offs),
+                        "v": _scatter_pool_rows(cache["v"], v, blk, offs)}
+                    if int8_cache:
+                        new_cache["k_sc"] = _scatter_pool_rows(
+                            cache["k_sc"], k_sc, blk, offs)
+                        new_cache["v_sc"] = _scatter_pool_rows(
+                            cache["v_sc"], v_sc, blk, offs)
                 from repro.kernels.paged_attention import (
                     merge_splitkv_partials)
                 ns = min(int(kv_splits), nb)
                 nbc = -(-nb // ns)
-                tblp = jnp.pad(block_tables, ((0, 0), (0, ns * nbc - nb)))
-                qf32 = q[:, 0].astype(jnp.float32)       # (B, KV, G, hd)
-                scale = hd ** -0.5
 
                 def cgather(pool):                       # (B, ns, nbc*bs, .)
                     g = pool[tblp]
                     return g.reshape(B, ns, nbc * bs_tok, *pool.shape[2:])
 
-                if int8_cache:
-                    kd = dqf(cgather(new_cache["k"]),
-                             cgather(new_cache["k_sc"]))
-                    vd = dqf(cgather(new_cache["v"]),
-                             cgather(new_cache["v_sc"]))
-                else:
-                    # no f32 materialization of the view: the contractions
-                    # below accumulate in f32 straight off the pool dtype
-                    kd, vd = cgather(new_cache["k"]), cgather(new_cache["v"])
-                idx = jnp.arange(ns * nbc * bs_tok).reshape(ns, nbc * bs_tok)
-                cvalid = idx[None] <= pos[:, None, None]
-                if window is not None:
-                    cvalid &= idx[None] > pos[:, None, None] - window
-                s = jnp.einsum("begh,bnseh->bnegs", qf32, kd,
-                               preferred_element_type=jnp.float32) * scale
-                s = jnp.where(cvalid[:, :, None, None, :], s, -1e30)
-                m_c = s.max(-1)                          # (B, ns, KV, G)
-                pr = jnp.exp(s - m_c[..., None])
-                acc = jnp.einsum("bnegs,bnseh->bnegh", pr, vd,
-                                 preferred_element_type=jnp.float32)
-                out = merge_splitkv_partials(acc, m_c, pr.sum(-1))
-                out = out[:, None].astype(q.dtype)       # (B, 1, KV, G, hd)
-                out = out.reshape(B, S, H * hd)
-                out = shard(out, "batch", "seq", "heads_act")
-                y = dense(p["wo"], out, tag="attn.wo", policy=pol, mode=mode)
-                y = checkpoint_name(
-                    shard(y, "batch", "seq_sp", "embed_act"), "block_out")
-                return y, new_cache
+                with obs.scope("attn/core"):
+                    tblp = jnp.pad(block_tables, ((0, 0), (0, ns * nbc - nb)))
+                    qf32 = q[:, 0].astype(jnp.float32)   # (B, KV, G, hd)
+                    scale = hd ** -0.5
+                    if int8_cache:
+                        kd = dqf(cgather(new_cache["k"]),
+                                 cgather(new_cache["k_sc"]))
+                        vd = dqf(cgather(new_cache["v"]),
+                                 cgather(new_cache["v_sc"]))
+                    else:
+                        # no f32 materialization of the view: the
+                        # contractions below accumulate in f32 straight off
+                        # the pool dtype
+                        kd, vd = cgather(new_cache["k"]), cgather(new_cache["v"])
+                    idx = jnp.arange(ns * nbc * bs_tok).reshape(ns, nbc * bs_tok)
+                    cvalid = idx[None] <= pos[:, None, None]
+                    if window is not None:
+                        cvalid &= idx[None] > pos[:, None, None] - window
+                    s = jnp.einsum("begh,bnseh->bnegs", qf32, kd,
+                                   preferred_element_type=jnp.float32) * scale
+                    s = jnp.where(cvalid[:, :, None, None, :], s, -1e30)
+                    m_c = s.max(-1)                      # (B, ns, KV, G)
+                    pr = jnp.exp(s - m_c[..., None])
+                    acc = jnp.einsum("bnegs,bnseh->bnegh", pr, vd,
+                                     preferred_element_type=jnp.float32)
+                    out = merge_splitkv_partials(acc, m_c, pr.sum(-1))
+                    out = out[:, None].astype(q.dtype)   # (B, 1, KV, G, hd)
+                return _attn_out(p, out, cfg=cfg, mode=mode), new_cache
 
             def gather(pool):
-                g = pool[block_tables]                   # (B, nb, bs_tok, ..)
-                return g.reshape(B, S_view, *pool.shape[2:])
+                # the read of the cache that attention pays for
+                with obs.scope("attn/core"):
+                    g = pool[block_tables]               # (B, nb, bs_tok, ..)
+                    return g.reshape(B, S_view, *pool.shape[2:])
 
-            kc = _cache_update(gather(cache["k"]), k, pos)
-            vc = _cache_update(gather(cache["v"]), v, pos)
-            if int8_cache:
-                ksc = _cache_update(gather(cache["k_sc"]), k_sc, pos)
-                vsc = _cache_update(gather(cache["v_sc"]), v_sc, pos)
-                kd, vd = dqf(kc, ksc), dqf(vc, vsc)
-            else:
-                kd, vd = kc, vc
+            with obs.scope("attn/kv_write"):
+                kc = _cache_update(gather(cache["k"]), k, pos)
+                vc = _cache_update(gather(cache["v"]), v, pos)
+                if int8_cache:
+                    ksc = _cache_update(gather(cache["k_sc"]), k_sc, pos)
+                    vsc = _cache_update(gather(cache["v_sc"]), v_sc, pos)
+            with obs.scope("attn/core"):
+                if int8_cache:
+                    kd, vd = dqf(kc, ksc), dqf(vc, vsc)
+                else:
+                    kd, vd = kc, vc
 
-            if S == 1:                                   # decode step
-                valid = jnp.arange(S_view)[None, :] <= pos[:, None]
-                if window is not None:  # local layer: paged by absolute
-                    # position, masked to the window (not ring-folded)
-                    valid &= jnp.arange(S_view)[None, :] > pos[:, None] - window
-                out = decode_attention(q, kd, vd, valid)
-            else:                                        # chunked prefill
-                # one or more request rows, each starting at its own pos;
-                # the causal mask from the per-row q_offset also blanks the
-                # not-yet-written pool tail (exact zeros after softmax, so
-                # garbage rows are inert)
-                out = flash_attention(q, kd, vd, causal=True, window=window,
-                                      q_offset=pos)
+                if S == 1:                               # decode step
+                    valid = jnp.arange(S_view)[None, :] <= pos[:, None]
+                    if window is not None:  # local layer: paged by absolute
+                        # position, masked to the window (not ring-folded)
+                        valid &= (jnp.arange(S_view)[None, :]
+                                  > pos[:, None] - window)
+                    out = decode_attention(q, kd, vd, valid)
+                else:                                    # chunked prefill
+                    # one or more request rows, each starting at its own
+                    # pos; the causal mask from the per-row q_offset also
+                    # blanks the not-yet-written pool tail (exact zeros after
+                    # softmax, so garbage rows are inert)
+                    out = flash_attention(q, kd, vd, causal=True,
+                                          window=window, q_offset=pos)
 
-            new_cache = {"k": _scatter_pool_rows(cache["k"], k, blk, offs),
-                         "v": _scatter_pool_rows(cache["v"], v, blk, offs)}
-            if int8_cache:
-                new_cache["k_sc"] = _scatter_pool_rows(cache["k_sc"], k_sc,
-                                                       blk, offs)
-                new_cache["v_sc"] = _scatter_pool_rows(cache["v_sc"], v_sc,
-                                                       blk, offs)
+            with obs.scope("attn/kv_write"):
+                new_cache = {
+                    "k": _scatter_pool_rows(cache["k"], k, blk, offs),
+                    "v": _scatter_pool_rows(cache["v"], v, blk, offs)}
+                if int8_cache:
+                    new_cache["k_sc"] = _scatter_pool_rows(cache["k_sc"], k_sc,
+                                                           blk, offs)
+                    new_cache["v_sc"] = _scatter_pool_rows(cache["v_sc"], v_sc,
+                                                           blk, offs)
         elif cache is not None:               # dense slot cache, decode S == 1
             int8_cache = cfg.kv_cache_dtype in KV_QUANT and "k_sc" in cache
-            if int8_cache:
-                qf, dqf = KV_QUANT[cfg.kv_cache_dtype]
-                k, k_sc = qf(k)
-                v, v_sc = qf(v)
-            if window is not None:            # ring buffer cache
-                kc = _ring_update(cache["k"], k, pos, window)
-                vc = _ring_update(cache["v"], v, pos, window)
+            with obs.scope("attn/kv_write"):
                 if int8_cache:
-                    ksc = _ring_update(cache["k_sc"], k_sc, pos, window)
-                    vsc = _ring_update(cache["v_sc"], v_sc, pos, window)
-                W = kc.shape[1]
-                filled = jnp.minimum(pos + 1, W)
-                valid = jnp.arange(W)[None, :] < filled[:, None]
-            else:
-                kc = _cache_update(cache["k"], k, pos)
-                vc = _cache_update(cache["v"], v, pos)
+                    qf, dqf = KV_QUANT[cfg.kv_cache_dtype]
+                    k, k_sc = qf(k)
+                    v, v_sc = qf(v)
+                if window is not None:            # ring buffer cache
+                    kc = _ring_update(cache["k"], k, pos, window)
+                    vc = _ring_update(cache["v"], v, pos, window)
+                    if int8_cache:
+                        ksc = _ring_update(cache["k_sc"], k_sc, pos, window)
+                        vsc = _ring_update(cache["v_sc"], v_sc, pos, window)
+                    W = kc.shape[1]
+                    with obs.scope("attn/core"):
+                        filled = jnp.minimum(pos + 1, W)
+                        valid = jnp.arange(W)[None, :] < filled[:, None]
+                else:
+                    kc = _cache_update(cache["k"], k, pos)
+                    vc = _cache_update(cache["v"], v, pos)
+                    if int8_cache:
+                        ksc = _cache_update(cache["k_sc"], k_sc, pos)
+                        vsc = _cache_update(cache["v_sc"], v_sc, pos)
+                    Sc = kc.shape[1]
+                    with obs.scope("attn/core"):
+                        valid = jnp.arange(Sc)[None, :] <= pos[:, None]
+                kc = shard(kc, "batch", "kv_seq", "kv_heads_act", None)
+                vc = shard(vc, "batch", "kv_seq", "kv_heads_act", None)
+            with obs.scope("attn/core"):
                 if int8_cache:
-                    ksc = _cache_update(cache["k_sc"], k_sc, pos)
-                    vsc = _cache_update(cache["v_sc"], v_sc, pos)
-                Sc = kc.shape[1]
-                valid = jnp.arange(Sc)[None, :] <= pos[:, None]
-            kc = shard(kc, "batch", "kv_seq", "kv_heads_act", None)
-            vc = shard(vc, "batch", "kv_seq", "kv_heads_act", None)
-            if int8_cache:
-                new_cache = {"k": kc, "v": vc, "k_sc": ksc, "v_sc": vsc}
-                out = decode_attention(q, dqf(kc, ksc), dqf(vc, vsc), valid)
-            else:
-                new_cache = {"k": kc, "v": vc}
-                out = decode_attention(q, kc, vc, valid)
+                    new_cache = {"k": kc, "v": vc, "k_sc": ksc, "v_sc": vsc}
+                    out = decode_attention(q, dqf(kc, ksc), dqf(vc, vsc),
+                                           valid)
+                else:
+                    new_cache = {"k": kc, "v": vc}
+                    out = decode_attention(q, kc, vc, valid)
         else:                                 # train / prefill
-            k = shard(k, "batch", "kv_seq", "kv_heads_act", None)
-            v = shard(v, "batch", "kv_seq", "kv_heads_act", None)
-            rep = cfg.kv_repeat
-            if rep > 1 and H % (KV * rep) == 0:
-                # replicate kv heads to the TP degree: every model shard gets
-                # its own q/kv head slice -> attention is TP-local (no per-
-                # layer kv all-gather). Cache keeps the unreplicated GQA kv.
-                ka = jnp.repeat(k, rep, axis=2)
-                va = jnp.repeat(v, rep, axis=2)
-                ka = shard(ka, "batch", "kv_seq", "kv_heads_act", None)
-                va = shard(va, "batch", "kv_seq", "kv_heads_act", None)
-                qa = q.reshape(B, S, KV * rep, H // (KV * rep), hd)
-                qa = shard(qa, "batch", "seq", "kv_heads_act", None, None)
-                out = flash_attention(qa, ka, va, causal=True, window=window,
-                                      segments=segments)
-                out = out.reshape(B, S, KV, G, hd)
-            else:
-                out = flash_attention(q, k, v, causal=True, window=window,
-                                      segments=segments)
+            with obs.scope("attn/kv_write"):  # the K/V the prefill collects
+                k = shard(k, "batch", "kv_seq", "kv_heads_act", None)
+                v = shard(v, "batch", "kv_seq", "kv_heads_act", None)
+            with obs.scope("attn/core"):
+                rep = cfg.kv_repeat
+                if rep > 1 and H % (KV * rep) == 0:
+                    # replicate kv heads to the TP degree: every model shard
+                    # gets its own q/kv head slice -> attention is TP-local
+                    # (no per-layer kv all-gather). Cache keeps the
+                    # unreplicated GQA kv.
+                    ka = jnp.repeat(k, rep, axis=2)
+                    va = jnp.repeat(v, rep, axis=2)
+                    ka = shard(ka, "batch", "kv_seq", "kv_heads_act", None)
+                    va = shard(va, "batch", "kv_seq", "kv_heads_act", None)
+                    qa = q.reshape(B, S, KV * rep, H // (KV * rep), hd)
+                    qa = shard(qa, "batch", "seq", "kv_heads_act", None, None)
+                    out = flash_attention(qa, ka, va, causal=True,
+                                          window=window, segments=segments)
+                    out = out.reshape(B, S, KV, G, hd)
+                else:
+                    out = flash_attention(q, k, v, causal=True, window=window,
+                                          segments=segments)
             new_cache = {"k": k, "v": v}
 
-    out = out.reshape(B, S, H * hd)
-    out = shard(out, "batch", "seq", "heads_act")
-    y = dense(p["wo"], out, tag="attn.wo", policy=pol, mode=mode)
-    y = checkpoint_name(shard(y, "batch", "seq_sp", "embed_act"), "block_out")
-    return y, new_cache
+    return _attn_out(p, out, cfg=cfg, mode=mode), new_cache
+
+
+def _attn_out(p: dict, out: jax.Array, *, cfg, mode: str) -> jax.Array:
+    """Attention output (B, S, KV, G, hd) -> the ``wo`` projection (B, S, D)."""
+    B, S = out.shape[:2]
+    with obs.scope("attn/out"):
+        out = out.reshape(B, S, cfg.n_heads * cfg.hd)
+        out = shard(out, "batch", "seq", "heads_act")
+        y = dense(p["wo"], out, tag="attn.wo", policy=cfg.quant, mode=mode)
+        return checkpoint_name(shard(y, "batch", "seq_sp", "embed_act"),
+                               "block_out")
 
 
 # --------------------------------------------------------------------------- #
@@ -688,16 +720,20 @@ def mlp_init(key, cfg, *, d_ff: Optional[int] = None, mode: str,
 def mlp_apply(p: dict, x: jax.Array, *, cfg, mode: str = "plain",
               tag: str = "mlp") -> jax.Array:
     pol = cfg.quant
-    up = dense(p["w_up"], x, tag=f"{tag}.w_up", policy=pol, mode=mode)
-    if "w_gate" in p:
-        g = dense(p["w_gate"], x, tag=f"{tag}.w_gate", policy=pol, mode=mode)
-        act = jax.nn.silu(g) if cfg.mlp == "swiglu" else jax.nn.gelu(g)
-        h = act * up
-    else:
-        h = jax.nn.gelu(up)
-    h = shard(h, "batch", "seq", "mlp_act")
-    y = dense(p["w_down"], h, tag=f"{tag}.w_down", policy=pol, mode=mode)
-    return checkpoint_name(shard(y, "batch", "seq_sp", "embed_act"), "block_out")
+    with obs.scope("mlp/up"):
+        up = dense(p["w_up"], x, tag=f"{tag}.w_up", policy=pol, mode=mode)
+        if "w_gate" in p:
+            g = dense(p["w_gate"], x, tag=f"{tag}.w_gate", policy=pol,
+                      mode=mode)
+            act = jax.nn.silu(g) if cfg.mlp == "swiglu" else jax.nn.gelu(g)
+            h = act * up
+        else:
+            h = jax.nn.gelu(up)
+        h = shard(h, "batch", "seq", "mlp_act")
+    with obs.scope("mlp/down"):
+        y = dense(p["w_down"], h, tag=f"{tag}.w_down", policy=pol, mode=mode)
+        return checkpoint_name(shard(y, "batch", "seq_sp", "embed_act"),
+                               "block_out")
 
 
 # --------------------------------------------------------------------------- #

@@ -25,6 +25,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import qlinear
 from repro.dist.sharding import shard
 from . import layers as L
@@ -325,16 +326,19 @@ def forward(
     B, S = tokens.shape
     dtype = jnp.dtype(cfg.dtype)
     table = params.get("tok_embed", params.get("in_embed"))
-    x = jnp.take(table, tokens, axis=0).astype(dtype)
-    x = shard(x, "batch", "seq_sp", "embed_act")
-    if vision_embed is not None:
-        nv = vision_embed.shape[1]
-        x = jnp.concatenate([vision_embed.astype(dtype), x[:, nv:]], axis=1)
-    if cfg.pos_embed == "learned":
-        if pos is None:
-            x = x + params["pos_embed"][None, :S].astype(dtype)
-        else:
-            x = x + jnp.take(params["pos_embed"], pos, axis=0)[:, None].astype(dtype)
+    with obs.scope("embed"):
+        x = jnp.take(table, tokens, axis=0).astype(dtype)
+        x = shard(x, "batch", "seq_sp", "embed_act")
+        if vision_embed is not None:
+            nv = vision_embed.shape[1]
+            x = jnp.concatenate([vision_embed.astype(dtype), x[:, nv:]],
+                                axis=1)
+        if cfg.pos_embed == "learned":
+            if pos is None:
+                x = x + params["pos_embed"][None, :S].astype(dtype)
+            else:
+                x = x + jnp.take(params["pos_embed"], pos,
+                                 axis=0)[:, None].astype(dtype)
 
     enc_out = None
     if cfg.is_encdec and audio_embed is not None:
@@ -349,58 +353,61 @@ def forward(
                               ring_tables=ring_tables, kv_splits=kv_splits)
 
     new_caches: dict = {}
-    if "blocks" in params:
-        decode = caches is not None
+    with obs.scope("layers"):  # the scan: its slicing and stacking
+        if "blocks" in params:
+            decode = caches is not None
 
-        def body(x, pc):
-            bp, bc = pc
-            x, nc = sb_fn(bp, x, bc)
-            out = nc if (decode or collect_cache) else None
-            return x, out
+            def body(x, pc):
+                bp, bc = pc
+                x, nc = sb_fn(bp, x, bc)
+                out = nc if (decode or collect_cache) else None
+                return x, out
 
-        cache_in = caches["blocks"] if decode else None
-        remat = cfg.remat if not decode else "none"
-        n_sb = cfg.n_superblocks
-        if (remat == "2level" and not decode and not collect_cache
-                and n_sb % max(cfg.remat_group, 1) == 0 and cfg.remat_group > 1):
-            # two-level (sqrt-ish) remat: outer scan saves only every
-            # remat_group-th residual; the inner scan re-runs under its own
-            # checkpoint during backward. Trades ~2x layer recompute for a
-            # remat_group-x smaller activation history — the knob that fits
-            # llama4-maverick train_4k (EXPERIMENTS.md §Perf).
-            G = cfg.remat_group
-            grouped = jax.tree.map(
-                lambda p: p.reshape(n_sb // G, G, *p.shape[1:]),
-                params["blocks"])
+            cache_in = caches["blocks"] if decode else None
+            remat = cfg.remat if not decode else "none"
+            n_sb = cfg.n_superblocks
+            if (remat == "2level" and not decode and not collect_cache
+                    and n_sb % max(cfg.remat_group, 1) == 0
+                    and cfg.remat_group > 1):
+                # two-level (sqrt-ish) remat: outer scan saves only every
+                # remat_group-th residual; the inner scan re-runs under its
+                # own checkpoint during backward. Trades ~2x layer recompute
+                # for a remat_group-x smaller activation history — the knob
+                # that fits llama4-maverick train_4k (EXPERIMENTS.md §Perf).
+                G = cfg.remat_group
+                grouped = jax.tree.map(
+                    lambda p: p.reshape(n_sb // G, G, *p.shape[1:]),
+                    params["blocks"])
 
-            def inner(x, gp):
-                x, _ = jax.lax.scan(_remat(body, "full"), x, (gp, None))
-                return x, None
+                def inner(x, gp):
+                    x, _ = jax.lax.scan(_remat(body, "full"), x, (gp, None))
+                    return x, None
 
-            x, _ = jax.lax.scan(jax.checkpoint(inner), x, grouped)
-            stacked_cache = None
-        else:
-            if remat == "2level":
-                remat = "full"
-            x, stacked_cache = jax.lax.scan(
-                _remat(body, remat), x, (params["blocks"], cache_in))
-        if stacked_cache is not None:
-            new_caches["blocks"] = stacked_cache
+                x, _ = jax.lax.scan(jax.checkpoint(inner), x, grouped)
+                stacked_cache = None
+            else:
+                if remat == "2level":
+                    remat = "full"
+                x, stacked_cache = jax.lax.scan(
+                    _remat(body, remat), x, (params["blocks"], cache_in))
+            if stacked_cache is not None:
+                new_caches["blocks"] = stacked_cache
 
-    if "rem" in params:
-        rem_cache = {}
-        for i in range(cfg.n_remainder):
-            lc = caches["rem"][f"r{i}"] if caches else None
-            lt = cfg.pattern[i]
-            x, nc = _apply_layer(params["rem"][f"r{i}"], x, cfg=cfg,
-                                 layer_type=lt, is_moe=mp[i], mode=mode,
-                                 positions=positions, enc_out=enc_out,
-                                 cache=lc, pos=pos, segments=segments,
-                                 block_tables=block_tables,
-                                 ring_tables=ring_tables, kv_splits=kv_splits)
-            rem_cache[f"r{i}"] = nc
-        if caches is not None or collect_cache:
-            new_caches["rem"] = rem_cache
+        if "rem" in params:
+            rem_cache = {}
+            for i in range(cfg.n_remainder):
+                lc = caches["rem"][f"r{i}"] if caches else None
+                lt = cfg.pattern[i]
+                x, nc = _apply_layer(params["rem"][f"r{i}"], x, cfg=cfg,
+                                     layer_type=lt, is_moe=mp[i], mode=mode,
+                                     positions=positions, enc_out=enc_out,
+                                     cache=lc, pos=pos, segments=segments,
+                                     block_tables=block_tables,
+                                     ring_tables=ring_tables,
+                                     kv_splits=kv_splits)
+                rem_cache[f"r{i}"] = nc
+            if caches is not None or collect_cache:
+                new_caches["rem"] = rem_cache
 
     x = L.norm_apply(params["final_norm"], x, cfg.norm)
     return x, (new_caches or None)
@@ -459,14 +466,16 @@ def prefill_to_cache(cfg, prefill_caches: dict, prefill_len: int,
         return out
 
     result: dict = {}
-    if "blocks" in prefill_caches:
-        result["blocks"] = {
-            f"l{i}": walk(prefill_caches["blocks"][f"l{i}"], cfg.pattern[i])
-            for i in range(len(cfg.pattern))}
-    if "rem" in prefill_caches:
-        result["rem"] = {
-            f"r{i}": walk(prefill_caches["rem"][f"r{i}"], cfg.pattern[i])
-            for i in range(cfg.n_remainder)}
+    with obs.scope("cache_fold"):
+        if "blocks" in prefill_caches:
+            result["blocks"] = {
+                f"l{i}": walk(prefill_caches["blocks"][f"l{i}"],
+                              cfg.pattern[i])
+                for i in range(len(cfg.pattern))}
+        if "rem" in prefill_caches:
+            result["rem"] = {
+                f"r{i}": walk(prefill_caches["rem"][f"r{i}"], cfg.pattern[i])
+                for i in range(cfg.n_remainder)}
     return result
 
 
@@ -476,16 +485,18 @@ def prefill_to_cache(cfg, prefill_caches: dict, prefill_len: int,
 
 def logits_fn(params: dict, cfg, hidden: jax.Array) -> jax.Array:
     """(B, S, D) -> (B, S, V), vocab-sharded."""
-    if cfg.tie_embeddings:
-        w = params["tok_embed"]                              # (V, D)
-        out = jnp.einsum("bsd,vd->bsv", hidden, w,
-                         preferred_element_type=jnp.float32)
-    else:
-        p = params["lm_head"]
-        w = qlinear.dequant_weight(p["qw"]).astype(hidden.dtype) if "qw" in p else p["w"]
-        out = jnp.einsum("bsd,dv->bsv", hidden, w,
-                         preferred_element_type=jnp.float32)
-    return shard(out, "batch", "seq", "vocab_act")
+    with obs.scope("lm_head"):
+        if cfg.tie_embeddings:
+            w = params["tok_embed"]                          # (V, D)
+            out = jnp.einsum("bsd,vd->bsv", hidden, w,
+                             preferred_element_type=jnp.float32)
+        else:
+            p = params["lm_head"]
+            w = (qlinear.dequant_weight(p["qw"]).astype(hidden.dtype)
+                 if "qw" in p else p["w"])
+            out = jnp.einsum("bsd,dv->bsv", hidden, w,
+                             preferred_element_type=jnp.float32)
+        return shard(out, "batch", "seq", "vocab_act")
 
 
 def chunked_ce_loss(params: dict, cfg, hidden: jax.Array, labels: jax.Array,

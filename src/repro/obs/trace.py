@@ -4,7 +4,9 @@ Host-side only: the tracer is fed from the engine's Python scheduling loop
 (never from inside a jit'd function), stores plain floats/ints, and imports
 no jax — tracing cannot change what the engine computes, add a jit cache
 entry, or touch device memory. With no tracer attached every engine hook is
-a single ``is None`` check.
+a single ``is None`` check. Each phase is also a ``jax.profiler`` host span
+``engine.<phase>`` (``obs.scope.host_span``), so a profiler trace shows the
+phases on the device trace's clock.
 
 Time comes from an injectable zero-arg monotonic clock (default
 ``time.monotonic``); all recorded stamps are relative to the tracer's
@@ -52,6 +54,7 @@ import time
 from typing import Optional
 
 from .metrics import summarize
+from .scope import host_span
 
 PHASES = ("admit", "prefill", "decode", "evict", "preempt", "compile")
 
@@ -99,7 +102,7 @@ class _Phase:
     enters three of these per step, so the contextlib machinery was
     measurable against sub-ms step times."""
 
-    __slots__ = ("tr", "name", "t0")
+    __slots__ = ("tr", "name", "t0", "span")
 
     def __init__(self, tr: "Tracer", name: str):
         self.tr = tr
@@ -109,12 +112,15 @@ class _Phase:
         tr = self.tr
         if tr._cur is None:                  # phase outside step: still sum
             tr.step_begin(len(tr.steps))
+        self.span = host_span("engine." + self.name)
+        self.span.__enter__()
         self.t0 = tr.now()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         tr = self.tr
         t1 = tr.now()
+        self.span.__exit__(exc_type, exc, tb)
         cur = tr._cur
         cur["phases"][self.name] = \
             cur["phases"].get(self.name, 0.0) + (t1 - self.t0)

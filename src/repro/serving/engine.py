@@ -1400,11 +1400,20 @@ class Engine:
         number of occupied slots. Streaming callbacks fire from inside this
         call, in generation order. With a tracer attached, the step is
         decomposed into admit / prefill / decode phases (evict / preempt /
-        compile nested inside whichever triggered them) and pool/queue
-        gauges are sampled at step end."""
+        compile nested inside whichever triggered them), pool/queue
+        gauges are sampled at step end, and the step is a
+        ``StepTraceAnnotation`` ``engine.step`` on the profiler's trace."""
         tr = self.tracer
-        if tr is not None:
+        if tr is None:
+            return self._step()
+        with jax.profiler.StepTraceAnnotation("engine.step",
+                                              step_num=self.steps):
             tr.step_begin(self.steps)
+            n = self._step()
+            tr.step_end(self._sample_gauges())
+        return n
+
+    def _step(self) -> int:
         with self._phase("admit"):
             self._admit()
         prefilling = [i for i, s in enumerate(self.slots)
@@ -1428,8 +1437,6 @@ class Engine:
             else:
                 self._do_decode()
         self.steps += 1
-        if tr is not None:
-            tr.step_end(self._sample_gauges())
         return sum(s.state != _FREE for s in self.slots)
 
     def run(self, max_steps: int = 10_000) -> dict:

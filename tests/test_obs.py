@@ -201,6 +201,54 @@ def test_tracing_zero_new_jit_entries_and_identical_tokens():
     assert any(k.startswith("jit_compiles_total") for k in snap["counters"])
 
 
+def _host_events(logdir) -> set:
+    """Names of the host spans in the one profiler trace under ``logdir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = logdir.glob("**/*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    return {ev.name for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+
+
+def test_engine_steps_and_phases_on_the_profiler_trace(tmp_path):
+    """With a tracer attached, each engine step and phase is a host span
+    on the profiler's clock; without one the engine records none."""
+    cfg, params = _setup()
+    traced = _tight_engine(cfg, params, tracer=Tracer())
+    plain = _tight_engine(cfg, params)
+    for eng in (traced, plain):
+        _submit_three(eng)
+        eng.step()                         # compiles outside the traces
+    with jax.profiler.trace(str(tmp_path / "traced")):
+        for _ in range(3):
+            traced.step()
+    with jax.profiler.trace(str(tmp_path / "plain")):
+        for _ in range(3):
+            plain.step()
+    assert {"engine.step", "engine.admit", "engine.prefill",
+            "engine.decode"} <= _host_events(tmp_path / "traced")
+    assert not {n for n in _host_events(tmp_path / "plain")
+                if n.startswith("engine.")}
+
+
+def test_serve_fixed_batch_steps_on_the_profiler_trace(tmp_path,
+                                                       monkeypatch):
+    """serve.py's fixed-batch loop names each step on the profiler's trace:
+    the prefill, then one span per decode step."""
+    import sys
+
+    from repro.launch import compile_cache, serve
+
+    monkeypatch.setattr(compile_cache, "enable", lambda: None)
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--arch", "qwen1.5-0.5b", "--smoke", "--batch", "2",
+        "--prompt-len", "8", "--gen", "3"])
+    with jax.profiler.trace(str(tmp_path)):
+        assert serve.main() == 0
+    assert {"prefill", "decode"} <= _host_events(tmp_path)
+
+
 def test_engine_counter_properties_assignable():
     """benchmarks/serving.py zeroes counters by assignment after warmup;
     the registry-backed properties must keep that working."""

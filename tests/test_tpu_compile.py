@@ -180,3 +180,64 @@ def test_bitsliced_compiles(one_chip, op, m, lookup_impl, words):
                              _bitsliced_operands(op, m, 2816, 1024), words,
                              w_bits=2, a_bits=8, lookup_impl=lookup_impl)
     assert "tpu_custom_call" in hlo
+
+
+def test_layer_names_reach_the_chips_program(one_chip, monkeypatch):
+    """The layer names of ``repro.obs.scope`` in a decode step compiled for
+    the chip: every Pallas kernel keeps its own ``kernel_metadata`` beside
+    its ``scope``, and with the names off the program is the same
+    instruction for instruction (kernel bodies compared without their
+    source locations, which name the calling lines)."""
+    import contextlib
+    import dataclasses
+    import re
+
+    from test_obs_scope import canonical_hlo
+
+    from repro import obs
+    from repro.configs import get_config
+    from repro.core.qplan import get_plan
+    from repro.launch import steps as St
+    from repro.models import lm
+
+    B, P = 8, 32
+    cfg = dataclasses.replace(
+        get_config("qwen1.5-0.5b"), n_layers=2, d_model=256, n_heads=4,
+        n_kv_heads=4, d_ff=704, vocab_size=4096,
+        quant=dataclasses.replace(get_plan("w2a16"), backend="pallas"))
+
+    def sds(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def decode_hlo():
+        params = sds(jax.eval_shape(lambda k: lm.quantize_tree(
+            lm.init_params(k, cfg), cfg), jax.random.PRNGKey(0)))
+        tokens = jax.ShapeDtypeStruct((B, P), jnp.int32, sharding=one_chip)
+        prefill = St.make_prefill_step(cfg, max_len=P + 4)
+        caches = sds(jax.eval_shape(
+            lambda p, t: prefill(p, {"tokens": t})[1], params, tokens))
+        batch = {"tokens": jax.ShapeDtypeStruct((B, 1), jnp.int32,
+                                                sharding=one_chip),
+                 "pos": jax.ShapeDtypeStruct((B,), jnp.int32,
+                                             sharding=one_chip)}
+        return jax.jit(St.make_decode_step(cfg)).lower(
+            params, caches, batch).compile().as_text()
+
+    was = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        named = decode_hlo()
+        monkeypatch.setattr(obs, "scope",
+                            lambda name: contextlib.nullcontext())
+        plain = decode_hlo()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", was)
+    kernels = [line for line in named.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 7                  # one per linear of a layer
+    for line in kernels:
+        assert re.search(r'frontend_attributes=\{kernel_metadata=\{\},'
+                         r'scope="(attn/(qkv|out)|mlp/(up|down))"\}', line)
+    assert "scope=" not in plain
+    assert canonical_hlo(named) == canonical_hlo(plain)
