@@ -16,6 +16,7 @@ local layers.
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -328,13 +329,47 @@ def _ring_update(cache: jax.Array, new: jax.Array, pos: jax.Array,
     return jax.vmap(upd)(cache, new, slot)
 
 
-def _cache_update(cache: jax.Array, new: jax.Array, pos: jax.Array) -> jax.Array:
-    """cache (B, S, KV, ...), new (B, 1, KV, ...), pos (B,)."""
+# Largest row of a layer's cache, in bytes, whose one new position is
+# written by a select over every position. K, V and their scales count
+# together, so one layer takes one route for all its arrays. Below it the
+# select reads and writes the whole row at HBM speed; above it the vmapped
+# ``dynamic_update_slice`` stays, which XLA lowers to a scatter that the
+# TPU compiler runs as a loop over rows, at a fixed cost a row whatever the
+# row's length. The two cost the same at about 5 MB a row on a TPU v5e
+# (PERF.md, Findings).
+KV_SELECT_MAX_ROW_BYTES = 4 * 2**20
 
-    def upd(c, n, s):
-        return jax.lax.dynamic_update_slice(c, n, (s,) + (0,) * (c.ndim - 1))
 
-    return jax.vmap(upd)(cache, new, pos)
+def _cache_update(cache: dict, new: dict, pos: jax.Array) -> dict:
+    """Write each sequence's new positions into a layer's cache arrays.
+
+    For each name ``n`` of ``new`` (K, V and, for a quantised cache, their
+    scales): ``cache[n]`` (B, S, KV, ...), ``new[n]`` (B, T, KV, ...). Row
+    b's T new positions start at ``pos[b]``, taken as
+    ``lax.dynamic_update_slice`` takes it: counted from the end if
+    negative, then clamped to [0, S - T]. Records the route taken in
+    ``kv_cache_write_total{route=...}`` at trace time.
+    """
+    names = list(new)
+    row_bytes = sum(math.prod(cache[n].shape[1:]) * cache[n].dtype.itemsize
+                    for n in names)
+    select = (all(new[n].shape[1] == 1 for n in names)
+              and row_bytes <= KV_SELECT_MAX_ROW_BYTES)
+    obs.metrics.inc("kv_cache_write_total",
+                    route="select" if select else "rows")
+    if select:
+        S = cache[names[0]].shape[1]
+        start = jnp.clip(jnp.where(pos < 0, pos + S, pos), 0, S - 1)
+        hit = jnp.arange(S)[None, :] == start[:, None]
+
+        def upd(c, x):
+            return jnp.where(hit.reshape(hit.shape + (1,) * (c.ndim - 2)), x, c)
+        return {n: upd(cache[n], new[n]) for n in names}
+
+    def upd_row(c, x, s):
+        return jax.lax.dynamic_update_slice(c, x, (s,) + (0,) * (c.ndim - 1))
+
+    return {n: jax.vmap(upd_row)(cache[n], new[n], pos) for n in names}
 
 
 def quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -587,11 +622,14 @@ def attn_apply(
                     return g.reshape(B, S_view, *pool.shape[2:])
 
             with obs.scope("attn/kv_write"):
-                kc = _cache_update(gather(cache["k"]), k, pos)
-                vc = _cache_update(gather(cache["v"]), v, pos)
+                new = {"k": k, "v": v}
                 if int8_cache:
-                    ksc = _cache_update(gather(cache["k_sc"]), k_sc, pos)
-                    vsc = _cache_update(gather(cache["v_sc"]), v_sc, pos)
+                    new.update(k_sc=k_sc, v_sc=v_sc)
+                upd = _cache_update({n: gather(cache[n]) for n in new}, new,
+                                    pos)
+                kc, vc = upd["k"], upd["v"]
+                if int8_cache:
+                    ksc, vsc = upd["k_sc"], upd["v_sc"]
             with obs.scope("attn/core"):
                 if int8_cache:
                     kd, vd = dqf(kc, ksc), dqf(vc, vsc)
@@ -640,11 +678,13 @@ def attn_apply(
                         filled = jnp.minimum(pos + 1, W)
                         valid = jnp.arange(W)[None, :] < filled[:, None]
                 else:
-                    kc = _cache_update(cache["k"], k, pos)
-                    vc = _cache_update(cache["v"], v, pos)
+                    new = {"k": k, "v": v}
                     if int8_cache:
-                        ksc = _cache_update(cache["k_sc"], k_sc, pos)
-                        vsc = _cache_update(cache["v_sc"], v_sc, pos)
+                        new.update(k_sc=k_sc, v_sc=v_sc)
+                    upd = _cache_update(cache, new, pos)
+                    kc, vc = upd["k"], upd["v"]
+                    if int8_cache:
+                        ksc, vsc = upd["k_sc"], upd["v_sc"]
                     Sc = kc.shape[1]
                     with obs.scope("attn/core"):
                         valid = jnp.arange(Sc)[None, :] <= pos[:, None]

@@ -12,7 +12,9 @@ process at a time may load the TPU compiler library, and every xdist worker
 imports this file.
 """
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -182,25 +184,15 @@ def test_bitsliced_compiles(one_chip, op, m, lookup_impl, words):
     assert "tpu_custom_call" in hlo
 
 
-def test_layer_names_reach_the_chips_program(one_chip, monkeypatch):
-    """The layer names of ``repro.obs.scope`` in a decode step compiled for
-    the chip: every Pallas kernel keeps its own ``kernel_metadata`` beside
-    its ``scope``, and with the names off the program is the same
-    instruction for instruction (kernel bodies compared without their
-    source locations, which name the calling lines)."""
-    import contextlib
-    import dataclasses
-    import re
-
-    from test_obs_scope import canonical_hlo
-
-    from repro import obs
+def _small_decode_hlo(one_chip, max_len, B=8, P=32):
+    """The chip's program of a fixed-batch decode step of a two-layer
+    qwen1.5 at w2a16 (d_model 256, 4 KV heads of 64, int8 cache of
+    ``max_len`` positions)."""
     from repro.configs import get_config
     from repro.core.qplan import get_plan
     from repro.launch import steps as St
     from repro.models import lm
 
-    B, P = 8, 32
     cfg = dataclasses.replace(
         get_config("qwen1.5-0.5b"), n_layers=2, d_model=256, n_heads=4,
         n_kv_heads=4, d_ff=704, vocab_size=4096,
@@ -210,31 +202,70 @@ def test_layer_names_reach_the_chips_program(one_chip, monkeypatch):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=one_chip), tree)
 
-    def decode_hlo():
-        params = sds(jax.eval_shape(lambda k: lm.quantize_tree(
-            lm.init_params(k, cfg), cfg), jax.random.PRNGKey(0)))
-        tokens = jax.ShapeDtypeStruct((B, P), jnp.int32, sharding=one_chip)
-        prefill = St.make_prefill_step(cfg, max_len=P + 4)
-        caches = sds(jax.eval_shape(
-            lambda p, t: prefill(p, {"tokens": t})[1], params, tokens))
-        batch = {"tokens": jax.ShapeDtypeStruct((B, 1), jnp.int32,
-                                                sharding=one_chip),
-                 "pos": jax.ShapeDtypeStruct((B,), jnp.int32,
-                                             sharding=one_chip)}
-        return jax.jit(St.make_decode_step(cfg)).lower(
-            params, caches, batch).compile().as_text()
+    params = sds(jax.eval_shape(lambda k: lm.quantize_tree(
+        lm.init_params(k, cfg), cfg), jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((B, P), jnp.int32, sharding=one_chip)
+    prefill = St.make_prefill_step(cfg, max_len=max_len)
+    caches = sds(jax.eval_shape(
+        lambda p, t: prefill(p, {"tokens": t})[1], params, tokens))
+    batch = {"tokens": jax.ShapeDtypeStruct((B, 1), jnp.int32,
+                                            sharding=one_chip),
+             "pos": jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)}
+    return jax.jit(St.make_decode_step(cfg)).lower(
+        params, caches, batch).compile().as_text()
+
+
+def _kernels(hlo):
+    return [line for line in hlo.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+# one position of the small decode step's cache: int8 K and V over 4 heads
+# of 64, and their f32 scales
+SMALL_POSITION_BYTES = 2 * 4 * 64 + 2 * 4 * 4
+
+
+@pytest.mark.parametrize("over", [False, True])
+def test_cache_write_row_loop_on_the_chip(one_chip, over):
+    """The cache write of a decode step compiled for the chip: a short
+    cache is written by a select, and no ``while`` of the program comes
+    from the ``attn/kv_write`` scatter; a cache whose row holds more than
+    ``KV_SELECT_MAX_ROW_BYTES`` keeps the scatter and its row loop. The
+    seven packed linears stand either way."""
+    from repro.models import layers as L
+
+    max_len = (L.KV_SELECT_MAX_ROW_BYTES // SMALL_POSITION_BYTES + 1 if over
+               else 32 + 4)
+    hlo = _small_decode_hlo(one_chip, max_len)
+    row_loops = [line for line in hlo.splitlines()
+                 if re.search(r' while\(.*op_name="[^"]*attn/kv_write'
+                              r'[^"]*scatter"', line)]
+    assert bool(row_loops) == over
+    assert len(_kernels(hlo)) == 7
+
+
+def test_layer_names_reach_the_chips_program(one_chip, monkeypatch):
+    """The layer names of ``repro.obs.scope`` in a decode step compiled for
+    the chip: every Pallas kernel keeps its own ``kernel_metadata`` beside
+    its ``scope``, and with the names off the program is the same
+    instruction for instruction (kernel bodies compared without their
+    source locations, which name the calling lines)."""
+    import contextlib
+
+    from test_obs_scope import canonical_hlo
+
+    from repro import obs
 
     was = jax.config.jax_traceback_in_locations_limit
     jax.config.update("jax_traceback_in_locations_limit", 0)
     try:
-        named = decode_hlo()
+        named = _small_decode_hlo(one_chip, 32 + 4)
         monkeypatch.setattr(obs, "scope",
                             lambda name: contextlib.nullcontext())
-        plain = decode_hlo()
+        plain = _small_decode_hlo(one_chip, 32 + 4)
     finally:
         jax.config.update("jax_traceback_in_locations_limit", was)
-    kernels = [line for line in named.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = _kernels(named)
     assert len(kernels) == 7                  # one per linear of a layer
     for line in kernels:
         assert re.search(r'frontend_attributes=\{kernel_metadata=\{\},'
