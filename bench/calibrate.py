@@ -29,7 +29,7 @@ def readings(cell: dict, config: dict, mix: dict, seed: int, seconds: float,
     overrides of ``harness.run``)."""
     import jax.numpy as jnp
 
-    from bench import check, harness, traffic, weights as W
+    from bench import check, configfile, harness, traffic, weights as W
 
     out = harness._drive_batch(
         cell, config, mix, seed=seed, seconds=seconds, trace=False,
@@ -38,7 +38,7 @@ def readings(cell: dict, config: dict, mix: dict, seed: int, seconds: float,
                 **{"program_cfg": None, "backend": None, "fault": None,
                    **program})
     sample = check.sample(out.finished, seed)
-    ref = check.load_reference(config)
+    ref = configfile.module(config, "reference")
     w = W.make_weights(config, seed)
     g = check.gaps(ref, w, config, sample,
                    length=-(-out.max_len // ref.Q_BLOCK) * ref.Q_BLOCK,

@@ -13,9 +13,10 @@ Each check is a number beside its limit (``harness.run`` adds
     a correct program only loses a near-tie, so the gap stays small; a wrong
     layer, token or cache row picks tokens the reference ranks far down.
 ``non_pallas_dispatches`` / ``pallas_dispatches``
-    The cell's packed weight op has to run as the TPU kernel: dispatches on
-    any other backend of any op are counted against a limit of 0, and the
-    packed op's own ``pallas`` dispatches have to be at least one.
+    The cell's packed weight ops have to run as the TPU kernel: dispatches
+    on any other backend of any op are counted against a limit of 0, and
+    each packed op's own ``pallas`` dispatches have to be at least one (the
+    number compared is the least of them).
 
 ``gaps(..., control_dtype=...)`` also reads the gap of the token that the
 control (the reference in a lower precision) ranks first at each position;
@@ -25,12 +26,9 @@ control (the reference in a lower precision) ranks first at each position;
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
-from pathlib import Path
 
 import numpy as np
 
-CONFIGS = Path(__file__).resolve().parent / "configs"
 SAMPLE_TOKENS = 512        # served tokens the sample reaches at least,
 SAMPLE_REQUESTS = 16       # unless it holds this many requests
 
@@ -48,21 +46,12 @@ def dispatch_counts(counters: dict) -> dict:
     return out
 
 
-def dispatch_checks(counts: dict, op: str) -> dict:
+def dispatch_checks(counts: dict, ops: list) -> dict:
     bad = sum(n for k, n in counts.items() if not k.endswith(":pallas"))
-    return {"pallas_dispatches": {"value": counts.get(f"{op}:pallas", 0),
-                                  "limit": 1, "at_least": True},
+    least = min(counts.get(f"{op}:pallas", 0) for op in ops)
+    return {"pallas_dispatches": {"value": least, "limit": 1,
+                                  "at_least": True},
             "non_pallas_dispatches": {"value": bad, "limit": 0}}
-
-
-def load_reference(config: dict):
-    """The configuration's plain reference module (``reference`` key)."""
-    path = CONFIGS / config["reference"]
-    spec = importlib.util.spec_from_file_location(
-        f"bench_reference_{path.stem}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 @dataclasses.dataclass
@@ -103,32 +92,18 @@ def gaps(ref, weights, config: dict, reqs: list, *, length: int,
          n_rows: int, control_dtype=None) -> dict:
     """Per sampled request, the gap at every served position: of the served
     token and, with ``control_dtype``, of the control's first choice."""
-    sizes, quant = reference_args(config)
     out = {"served": [], "control": []}
     for r in reqs:
         tokens, rows = _rows(r)
-        lg = ref.logits(weights, sizes, quant, tokens, rows, length=length,
+        lg = ref.logits(weights, config, tokens, rows, length=length,
                         n_rows=n_rows)
         best = lg.max(-1)
         served = lg[np.arange(len(rows)), np.asarray(r.out)]
         out["served"].append(best - served)
         if control_dtype is not None:
-            lc = ref.logits(weights, sizes, quant, tokens, rows,
-                            length=length, n_rows=n_rows,
-                            act_dtype=control_dtype)
+            lc = ref.logits(weights, config, tokens, rows, length=length,
+                            n_rows=n_rows, act_dtype=control_dtype)
             pick = lc.argmax(-1)
             out["control"].append(best - lg[np.arange(len(rows)), pick])
     return out
 
-
-def reference_args(config: dict) -> tuple[dict, dict]:
-    """The sizes and quantisation statement the reference reads, as flat
-    dicts of numbers."""
-    keys = ("hidden_size", "intermediate_size", "num_hidden_layers",
-            "num_attention_heads", "num_key_value_heads", "vocab_size",
-            "rope_theta", "rms_norm_eps")
-    sizes = {k: config[k] for k in keys}
-    q = config["quant"]
-    quant = {"weight_bits": q["weight_bits"], "act_bits": q["act_bits"],
-             "kv_cache_levels": q["kv_cache_levels"]}
-    return sizes, quant

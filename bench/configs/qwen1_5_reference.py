@@ -5,7 +5,10 @@ It follows the published architecture (hf:Qwen/Qwen1.5-0.5B): token
 embedding, per layer RMSNorm -> attention with QKV bias and rotary
 positions (rotate-half, theta from the file) -> residual -> RMSNorm ->
 SwiGLU MLP -> residual, a final RMSNorm and the tied embedding as the
-head. On top of it, the quantisation the configuration file states under
+head. Under grouped-query attention each key/value head serves
+``num_attention_heads / num_key_value_heads`` consecutive query heads;
+with ``tie_word_embeddings`` false the head is its own (hidden, vocab)
+matrix. On top of it, the quantisation the configuration file states under
 ``quant``, written here from that statement and not taken from the program:
 
 - weights: each output channel's codes are ``clip(round(w / s), -2^(b-1),
@@ -66,9 +69,13 @@ def _rope(x, positions, theta):
 
 
 def _attention(q, k, v):
-    """Causal softmax attention, q/k/v (T, H, hd) -> (T, H * hd), in
-    blocks of Q_BLOCK query rows."""
+    """Causal softmax attention, q (T, H, hd), k/v (T, KV, hd) -> (T, H *
+    hd), in blocks of Q_BLOCK query rows; query head h reads key/value head
+    h // (H / KV)."""
     T, H, hd = q.shape
+    if k.shape[1] != H:
+        k = jnp.repeat(k, H // k.shape[1], axis=1)
+        v = jnp.repeat(v, H // v.shape[1], axis=1)
     nb = T // Q_BLOCK
     kpos = jnp.arange(T)
 
@@ -89,6 +96,7 @@ def _attention(q, k, v):
 def _hidden(weights, tokens, *, sizes, quant, act_dtype):
     sizes, quant = dict(sizes), dict(quant)
     D, H = sizes["hidden_size"], sizes["num_attention_heads"]
+    KV = sizes["num_key_value_heads"]
     hd = D // H
     eps, theta = sizes["rms_norm_eps"], sizes["rope_theta"]
     wb, ab, kvn = (quant["weight_bits"], quant["act_bits"],
@@ -117,8 +125,8 @@ def _hidden(weights, tokens, *, sizes, quant, act_dtype):
         a = p["attn"]
         h = _rms(x, p["ln1"]["scale"], eps)
         q = linear(h, a["wq"]).reshape(T, H, hd)
-        k = linear(h, a["wk"]).reshape(T, H, hd)
-        v = linear(h, a["wv"]).reshape(T, H, hd)
+        k = linear(h, a["wk"]).reshape(T, KV, hd)
+        v = linear(h, a["wv"]).reshape(T, KV, hd)
         q, k = _rope(q, positions, theta), _rope(k, positions, theta)
         k, v = _fake_quant(k, -kvn, kvn), _fake_quant(v, -kvn, kvn)
         x = keep(x + linear(_attention(q, k, v), a["wo"]))
@@ -127,26 +135,37 @@ def _hidden(weights, tokens, *, sizes, quant, act_dtype):
         g = jax.nn.silu(linear(h, m["w_gate"])) * linear(h, m["w_up"])
         return keep(x + linear(g, m["w_down"])), None
 
-    x = keep(weights["tok_embed"][tokens].astype(jnp.float32))
+    embed = weights["tok_embed" if sizes["tie_word_embeddings"]
+                    else "in_embed"]
+    x = keep(embed[tokens].astype(jnp.float32))
     x, _ = jax.lax.scan(layer, x, weights["blocks"]["l0"])
     return _rms(x, weights["final_norm"]["scale"], eps)
 
 
-@functools.partial(jax.jit, static_argnames=("act_dtype",))
-def _head(weights, h, rows, *, act_dtype):
+@functools.partial(jax.jit, static_argnames=("tied", "act_dtype"))
+def _head(weights, h, rows, *, tied, act_dtype):
     h = h[rows]
     if act_dtype is not None:
         h = h.astype(act_dtype).astype(jnp.float32)
-    return h @ weights["tok_embed"].astype(jnp.float32).T
+    if tied:
+        return h @ weights["tok_embed"].astype(jnp.float32).T
+    return h @ weights["lm_head"]["w"].astype(jnp.float32)
 
 
-def logits(weights, sizes: dict, quant: dict, tokens: np.ndarray,
-           rows: np.ndarray, *, length: int, n_rows: int,
-           act_dtype=None) -> np.ndarray:
+SIZES = ("hidden_size", "intermediate_size", "num_hidden_layers",
+         "num_attention_heads", "num_key_value_heads", "vocab_size",
+         "rope_theta", "rms_norm_eps", "tie_word_embeddings")
+QUANT = ("weight_bits", "act_bits", "kv_cache_levels")
+
+
+def logits(weights, config: dict, tokens: np.ndarray, rows: np.ndarray, *,
+           length: int, n_rows: int, act_dtype=None) -> np.ndarray:
     """(len(rows), V) float32 logits at positions ``rows`` of one causal
     forward over ``tokens``, padded at the end to ``length`` (a multiple of
     Q_BLOCK; padding cannot reach earlier positions), with ``rows`` padded
-    to ``n_rows``: every sequence of a cell runs the same two programs."""
+    to ``n_rows``: every sequence of a cell runs the same two programs.
+    ``config`` is the configuration file: its sizes and, under ``quant``,
+    the quantisation it states."""
     if length % Q_BLOCK or len(tokens) > length or len(rows) > n_rows:
         raise ValueError(f"length {length}, n_rows {n_rows}: tokens "
                          f"{len(tokens)}, rows {len(rows)}")
@@ -154,9 +173,11 @@ def logits(weights, sizes: dict, quant: dict, tokens: np.ndarray,
     tok[:len(tokens)] = tokens
     r = np.zeros((n_rows,), np.int32)
     r[:len(rows)] = rows
-    key = lambda d: tuple(sorted(d.items()))  # noqa: E731
+    sizes = tuple(sorted((k, config[k]) for k in SIZES))
+    quant = tuple(sorted((k, config["quant"][k]) for k in QUANT))
     with jax.default_matmul_precision("highest"):
-        h = _hidden(weights, jnp.asarray(tok), sizes=key(sizes),
-                    quant=key(quant), act_dtype=act_dtype)
-        out = _head(weights, h, jnp.asarray(r), act_dtype=act_dtype)
+        h = _hidden(weights, jnp.asarray(tok), sizes=sizes, quant=quant,
+                    act_dtype=act_dtype)
+        out = _head(weights, h, jnp.asarray(r),
+                    tied=config["tie_word_embeddings"], act_dtype=act_dtype)
     return np.asarray(out[:len(rows)], np.float32)

@@ -8,7 +8,9 @@ points only: ``repro.launch.serve`` (``build_parser``, ``validate_args``,
 ``repro.launch.steps.make_prefill_step`` / ``make_decode_step`` that
 ``serve`` runs without ``--paged``, ``repro.models.lm.quantize_tree`` and
 ``repro.configs.get_config``; and it reads the program's kernel dispatch
-counters.
+counters. What depends on the model's layout (weights, reference, work
+counts, the program attributes compared) comes from the configuration's
+own files (``bench/configfile.py``).
 
 The loop it drives is serve.py's fixed-batch loop (the mix's ``loop`` is
 ``"batch"``): one prefill of ``slots`` prompts of one length, then one
@@ -43,7 +45,8 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import check, traffic as traffic_mod, trace_reduce, weights as W
+from bench import (check, configfile, traffic as traffic_mod, trace_reduce,
+                   weights as W)
 
 LAYER_METRICS = Path(__file__).resolve().parent / "layer_metrics"
 TRACE_SEGMENTS = 8      # traced stretches of the window's first batch
@@ -93,22 +96,26 @@ def _load_reader(name: str):
     return mod.read
 
 
+def program_value(cfg, attr: str):
+    """The program config's ``attr``; a dotted name reads a nested one."""
+    for name in attr.split("."):
+        cfg = getattr(cfg, name)
+    return cfg
+
+
 def _program(config: dict, mix: dict, program_cfg=None, backend=None):
     """The program config of a configuration under a mix, as ``serve``
-    would build it."""
+    would build it. It has to agree with the file on every key of the
+    file's ``program_keys``."""
     import dataclasses as dc
 
     from repro.configs import get_config
     from repro.launch import serve
 
     cfg = program_cfg if program_cfg is not None else get_config(config["arch"])
-    want = {"n_layers": "num_hidden_layers", "d_model": "hidden_size",
-            "n_heads": "num_attention_heads", "n_kv_heads":
-            "num_key_value_heads", "d_ff": "intermediate_size",
-            "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-            "tie_embeddings": "tie_word_embeddings"}
-    diff = {k: (getattr(cfg, k), config[v]) for k, v in want.items()
-            if getattr(cfg, k) != config[v]}
+    diff = {k: (program_value(cfg, k), config[v])
+            for k, v in config["program_keys"].items()
+            if program_value(cfg, k) != config[v]}
     if diff:
         raise ValueError(f"program config {cfg.name} differs from "
                          f"{config['name']}: {diff}")
@@ -350,11 +357,11 @@ def run(cell: dict, config: dict, mix: dict, metrics: dict, *, seed: int,
                 peaks=peaks, t_start=t_start, program_cfg=program_cfg,
                 backend=backend, fault=fault, builds=_Builds())
 
-    checks = check.dispatch_checks(out.dispatch, config["packed_op"])
+    checks = check.dispatch_checks(out.dispatch, configfile.packed_ops(config))
     checks["compiles_in_window"] = {"value": out.compiles_in_window,
                                     "limit": 0}
     sample = check.sample(out.finished, seed)
-    ref = check.load_reference(config)
+    ref = configfile.module(config, "reference")
     ref_weights = W.make_weights(config, seed)
     g = check.gaps(ref, ref_weights, config, sample,
                    length=-(-out.max_len // ref.Q_BLOCK) * ref.Q_BLOCK,

@@ -1,5 +1,5 @@
 """Model operations of the traced window's tokens over the window and the
-bf16 peak (bench/work/model_flops.py)."""
+bf16 peak (the configuration's ``work`` module)."""
 
 from bench import readers
 

@@ -9,13 +9,16 @@ from __future__ import annotations
 import re
 import sys
 
-from bench.work import model_flops, packed_gemm
+from bench import configfile
+from bench.work import packed_gemm
 
 # device operations of each packed weight op, found by their HLO text and
 # not by name: a Pallas kernel is a custom call with the target
 # "tpu_custom_call", and the packed weight kernels alone take a uint8
 # operand (the packed codes); the instruction's name follows the jitted
-# wrapper or the pallas_call's ``name=`` and may change
+# wrapper or the pallas_call's ``name=`` and may change. The pattern matches
+# every packed kernel: a configuration that runs two of them gives its
+# readers narrower patterns
 PACKED_OP_EVENTS = {
     "dequant_matmul":
         r'custom-call\(.*?\bu8\[.*custom_call_target="tpu_custom_call"'}
@@ -46,67 +49,84 @@ def idle_share(run):
 
 def mfu(run):
     """Model operations of every token the traced window processed, over
-    the window and the chip's bf16 peak, in percent."""
+    the window and the chip's bf16 peak, in percent. The operations come
+    from the configuration's ``work`` module."""
     if run.trace is None:
         return None
     cfg = run.config
-    ops = sum(model_flops.decode_ops(cfg, c) for c in run.decode_contexts)
-    ops += sum(model_flops.prompt_ops(cfg, p, n)
-               for p, n in run.prompt_segments)
-    ops += run.prompt_heads * model_flops.head_ops(cfg)
+    work = configfile.module(cfg, "work")
+    ops = sum(work.decode_ops(cfg, c) for c in run.decode_contexts)
+    ops += sum(work.prompt_ops(cfg, p, n) for p, n in run.prompt_segments)
+    ops += run.prompt_heads * work.head_ops(cfg)
     if ops <= 0:
         return None
     return 100.0 * ops / (run.trace.window_s * run.peaks["bf16_flops"])
 
 
-def packed_gemm_roofline(run):
-    """The packed linears' least time at the chip's peaks over their device
-    time, in percent. Every decode step runs each linear once over
-    ``decode_rows`` rows, every prefill step once over ``prefill_rows``.
+def op_time(run, events: str, calls: int):
+    """Device time of the events whose HLO text matches ``events``, over
+    both step executables, where each decode step makes ``calls`` calls of
+    the op and each prefill step as many.
 
-    The device time is that of the events of the op the dispatch counters
-    name, taken executable by executable. The profiler may keep fewer
+    The time is taken executable by executable. The profiler may keep fewer
     events than ran, with no mark in the trace (seen on a v5e: a decode
     run's kernel events about 1% short, its busy and executable times
-    whole); an executable's time is then its traced calls' mean times its calls,
-    where at least MIN_TRACED of them were traced. More events than calls,
-    or events outside both step executables, leave the metric unread."""
-    if run.trace is None:
-        return None
-    op = run.config["packed_op"]
-    if not any(k.startswith(op + ":") for k in run.dispatch):
-        return None
-    hits = run.trace.op_hits(PACKED_OP_EVENTS[op])
-    q = run.config["quant"]
-    shapes = packed_gemm.layer_shapes(run.config)
-    L = run.config["num_hidden_layers"]
-    t = least = 0.0
-    placed = 0
-    for module, steps, M in (
-            (run.decode_module, run.counters["decode_steps"], run.decode_rows),
-            (run.prefill_module, run.counters["prefill_steps"],
-             run.prefill_rows)):
+    whole); an executable's time is then its traced calls' mean times its
+    calls, where at least MIN_TRACED of them were traced. More events than
+    calls, or events outside both step executables, give None."""
+    hits = run.trace.op_hits(events)
+    t, placed = 0.0, 0
+    for module, steps in ((run.decode_module, run.counters["decode_steps"]),
+                          (run.prefill_module,
+                           run.counters["prefill_steps"])):
         mine = [v for k, v in hits.items()
                 if re.search(module, k.split("/", 1)[0])]
         placed += len(mine)
         s, n = sum(v[0] for v in mine), sum(v[1] for v in mine)
-        calls = steps * L * len(shapes)
-        if n > calls or n < MIN_TRACED * calls:
-            print(f"packed_gemm_roofline: {n} events of {op} in {module}, "
-                  f"{calls} calls: not read", file=sys.stderr)
+        want = steps * calls
+        if n > want or n < MIN_TRACED * want:
+            print(f"op_time: {n} events of {events!r} in {module}, {want} "
+                  "calls: not read", file=sys.stderr)
             return None
-        if not calls:
+        if not want:
             continue
-        if n < calls:
-            print(f"packed_gemm_roofline: {n} of {calls} calls of {op} in "
-                  f"{module} traced: their mean time taken for all",
-                  file=sys.stderr)
-        t += s * calls / n
-        least += steps * L * sum(packed_gemm.roofline_s(
-            M, K, N, run.peaks, w_bits=q["weight_bits"], a_bits=q["act_bits"])
-            for K, N in shapes)
+        if n < want:
+            print(f"op_time: {n} of {want} calls in {module} traced: their "
+                  "mean time taken for all", file=sys.stderr)
+        t += s * want / n
     if placed != len(hits):
-        print(f"packed_gemm_roofline: events of {op} outside the step "
-              "executables: not read", file=sys.stderr)
+        print(f"op_time: events of {events!r} outside the step executables: "
+              "not read", file=sys.stderr)
         return None
-    return 100.0 * least / t if t > 0 else None
+    return t
+
+
+def packed_gemm_roofline(run, events=None):
+    """The dense packed linears' least time at the chip's peaks over their
+    device time, in percent. The linears are the configuration's ``work``
+    module's ``dense_calls``, run by its first packed op: every decode step
+    runs each of them over ``decode_rows`` rows, every prefill step over
+    ``prefill_rows``.
+
+    ``events`` is the pattern of the op's events' HLO text
+    (``PACKED_OP_EVENTS[op]`` by default; a configuration whose other
+    packed ops would match it gives a narrower one). The device time is
+    ``op_time``'s; where it gives None, or the op was not dispatched, the
+    metric is left unread."""
+    if run.trace is None:
+        return None
+    op = configfile.packed_ops(run.config)[0]
+    if not any(k.startswith(op + ":") for k in run.dispatch):
+        return None
+    calls = configfile.module(run.config, "work").dense_calls(run.config)
+    t = op_time(run, events or PACKED_OP_EVENTS[op],
+                sum(c for _, _, c in calls))
+    if not t:
+        return None
+    q = run.config["quant"]
+    least = sum(steps * c * packed_gemm.roofline_s(
+        M, K, N, run.peaks, w_bits=q["weight_bits"], a_bits=q["act_bits"])
+        for steps, M in ((run.counters["decode_steps"], run.decode_rows),
+                         (run.counters["prefill_steps"], run.prefill_rows))
+        for K, N, c in calls)
+    return 100.0 * least / t

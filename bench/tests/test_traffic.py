@@ -65,13 +65,19 @@ def test_traced_stretches_span_the_batch(steps, want):
 
 
 def test_files_named_by_benchmark_load():
-    from bench import check
+    from bench import configfile
 
     for c in BENCH["configs"]:
         config = json.loads((ROOT / c["file"]).read_text())
         assert config["name"] == c["name"]
         assert config["reduced"] == c["reduced"]
-        assert hasattr(check.load_reference(config), "logits")
+        assert config["program_keys"] and configfile.packed_ops(config)
+        for key, names in (("reference", ("logits", "Q_BLOCK")),
+                           ("weights", ("make_weights",)),
+                           ("work", ("dense_calls", "decode_ops",
+                                     "prompt_ops", "head_ops"))):
+            mod = configfile.module(config, key)
+            assert all(hasattr(mod, n) for n in names), (key, names)
     for w in BENCH["workloads"]:
         mix = json.loads((ROOT / "bench" / "traffic" /
                           f"{w['traffic']}.json").read_text())

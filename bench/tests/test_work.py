@@ -9,13 +9,14 @@ from pathlib import Path
 
 import pytest
 
-from bench import peaks, readers
+from bench import configfile, peaks, readers
 from bench.harness import RunView
 from bench.trace_reduce import Reduced
-from bench.work import model_flops, packed_gemm
+from bench.work import packed_gemm
 
 ROOT = Path(__file__).resolve().parents[2]
 QWEN = json.loads((ROOT / "bench/configs/qwen1.5-0.5b-w2a16.json").read_text())
+WORK = configfile.module(QWEN, "work")
 V5E = peaks.peaks_for("TPU v5 lite")
 
 
@@ -47,16 +48,39 @@ def test_roofline_picks_the_binding_bound():
 
 
 def test_model_flops_hand_counts():
-    shapes = packed_gemm.layer_shapes(QWEN)
-    assert shapes == [(1024, 1024)] * 4 + [(1024, 2816)] * 2 + [(2816, 1024)]
+    calls = WORK.dense_calls(QWEN)
+    assert calls == [(1024, 1024, 24)] * 4 + [(1024, 2816, 24)] * 2 + \
+        [(2816, 1024, 24)]
     per_layer = 2 * (4 * 1024 * 1024 + 3 * 1024 * 2816)
-    assert model_flops.linear_ops(QWEN) == 24 * per_layer
-    assert model_flops.head_ops(QWEN) == 2 * 1024 * 151936
-    assert model_flops.decode_ops(QWEN, 100) == \
+    assert WORK.linear_ops(QWEN) == 24 * per_layer
+    assert WORK.head_ops(QWEN) == 2 * 1024 * 151936
+    assert WORK.decode_ops(QWEN, 100) == \
         24 * per_layer + 4 * 100 * 1024 * 24 + 2 * 1024 * 151936
     # three prompt tokens at positions 5, 6, 7: contexts 5 + 6 + 7 = 18
-    assert model_flops.prompt_ops(QWEN, 5, 3) == \
+    assert WORK.prompt_ops(QWEN, 5, 3) == \
         3 * 24 * per_layer + 4 * 18 * 1024 * 24
+
+
+def test_gqa_untied_hand_counts():
+    # codeqwen1.5-7b's widths with grouped-query attention: 32 query heads
+    # of 128 over 4 key/value heads, an untied 92416-row head
+    gqa = dict(QWEN, hidden_size=4096, intermediate_size=13440,
+               num_hidden_layers=32, num_attention_heads=32,
+               num_key_value_heads=4, vocab_size=92416,
+               tie_word_embeddings=False)
+    calls = WORK.dense_calls(gqa)
+    assert calls == [(4096, 4096, 32), (4096, 512, 32), (4096, 512, 32),
+                     (4096, 4096, 32), (4096, 13440, 32), (4096, 13440, 32),
+                     (13440, 4096, 32)]
+    per_layer = 2 * (2 * 4096 * 4096 + 2 * 4096 * 512 + 3 * 4096 * 13440)
+    assert WORK.linear_ops(gqa) == 32 * per_layer
+    # the head is counted once, whether or not it shares the embedding
+    assert WORK.head_ops(gqa) == 2 * 4096 * 92416
+    # attention counts the 32 query heads: 4 t d_model a layer
+    assert WORK.decode_ops(gqa, 100) == \
+        32 * per_layer + 4 * 100 * 4096 * 32 + 2 * 4096 * 92416
+    assert WORK.prompt_ops(gqa, 5, 3) == \
+        3 * 32 * per_layer + 4 * 18 * 4096 * 32
 
 
 def _view(**kw):
@@ -79,9 +103,9 @@ KERNEL_TEXT = (  # an XLA Ops event of the packed kernel on a v5e
 
 def test_readers_arithmetic():
     def least(steps):
-        return steps * 24 * sum(packed_gemm.roofline_s(
+        return steps * sum(c * packed_gemm.roofline_s(
             32, k, n, V5E, w_bits=2, a_bits=None)
-            for k, n in packed_gemm.layer_shapes(QWEN))
+            for k, n, c in WORK.dense_calls(QWEN))
 
     dec = "jit_decode_step/dequant_matmul_pallas.7"
     pre = "jit_prefill_step/dequant_matmul_pallas.3"
@@ -97,7 +121,7 @@ def test_readers_arithmetic():
     assert readers.decode_step_ms(v) == pytest.approx(2.0)
     assert readers.idle_share(v) == pytest.approx(75.0)
     assert readers.mfu(v) == pytest.approx(
-        100 * 4 * model_flops.decode_ops(QWEN, 100) / 197e12)
+        100 * 4 * WORK.decode_ops(QWEN, 100) / 197e12)
     # the kernel is found by its HLO text, whatever its instruction's name
     red.texts[dec] = KERNEL_TEXT.replace("%dequant_matmul_pallas.7",
                                          "%w2a16_matmul.7")
@@ -112,6 +136,10 @@ def test_readers_arithmetic():
         assert readers.packed_gemm_roofline(v) is None
     red.ops[dec] = [4 * least(10), 1680]
     assert readers.packed_gemm_roofline(v) == pytest.approx(25.0)
+    # a narrower pattern, as a configuration with a second packed op gives
+    assert readers.packed_gemm_roofline(
+        v, events=r"u8\[256,176\]") == pytest.approx(25.0)
+    assert readers.packed_gemm_roofline(v, events=r"u8\[999,") is None
     # kernel events outside both step executables: nothing to read
     red.ops["no module/dequant_matmul_pallas.9"] = [1.0, 1]
     red.texts["no module/dequant_matmul_pallas.9"] = KERNEL_TEXT

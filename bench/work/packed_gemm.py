@@ -35,12 +35,3 @@ def roofline_s(M: int, K: int, N: int, peaks: dict, *, w_bits: int,
     return max(ops(M, K, N) / peak,
                bytes_moved(M, K, N, w_bits=w_bits, a_bits=a_bits,
                            group_size=group_size) / peaks["hbm_bytes_per_s"])
-
-
-def layer_shapes(sizes: dict) -> list[tuple[int, int]]:
-    """(K, N) of the packed linears of one decoder layer of a configuration
-    file's sizes: q, k, v, o, gate, up, down."""
-    D, F = sizes["hidden_size"], sizes["intermediate_size"]
-    hd = D // sizes["num_attention_heads"]
-    kv = sizes["num_key_value_heads"] * hd
-    return [(D, D), (D, kv), (D, kv), (D, D), (D, F), (D, F), (F, D)]
